@@ -73,6 +73,8 @@ def main() -> None:
 
     import importlib
     import inspect
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows, gates = [], []
     for key, (mod_name, _, smokeable) in BENCHES.items():
         if only is not None and key not in only:

@@ -172,7 +172,8 @@ class Session:
         self._mlp = None                 # (weights, biases, lr)
         self._round = 0
         self.round_stats: List[RoundStats] = []
-        self._serve_models: dict = {}    # (arch, tiny, seed) -> model, params
+        self._serve_models: dict = {}    # (arch|cfg, tiny, seed) -> model,
+                                         # params
         self._serve_batchers: dict = {}  # + (coded_layers, admission) ->
                                          # ContinuousBatcher (compiled steps,
                                          # pre-encoded weights, warm buckets)
@@ -191,6 +192,10 @@ class Session:
         if not self._closed:
             self._closed = True
             self.engine.close()
+            # drop served models and their pre-encoded shards: device
+            # memory goes back as soon as the caller drops the session
+            self._serve_models.clear()
+            self._serve_batchers.clear()
 
     @property
     def closed(self) -> bool:
@@ -296,14 +301,62 @@ class Session:
         return float((acts[-1].argmax(1) == y).mean())
 
     # ------------------------------------------------------------- serving
-    def serve(self, arch: str = "qwen2-7b", *, tiny: bool = True,
+    def _serve_model(self, arch, tiny: bool, seed: int):
+        """(model, params, cache key) for an arch name (its tiny config
+        when ``tiny``) or a ``ModelConfig`` used as given — e.g. one cut
+        to a chip's share of a published model."""
+        import jax
+        from ..configs import get_config, tiny_config
+        from ..models import build_model
+        if isinstance(arch, str):
+            mkey = (arch, tiny, seed)
+            cfg = tiny_config(arch) if tiny else get_config(arch)
+        else:
+            mkey = (arch, False, seed)
+            cfg = arch
+        if mkey not in self._serve_models:
+            model = build_model(cfg)
+            self._serve_models[mkey] = (model,
+                                        model.init(jax.random.PRNGKey(seed)))
+        return self._serve_models[mkey] + (mkey,)
+
+    def batcher(self, arch="qwen2-7b", *, tiny: bool = True, seed: int = 0,
+                coded_layers: Optional[str] = None,
+                admission: str = "continuous"):
+        """The session's :class:`~repro.runtime.serve_loop.ContinuousBatcher`
+        for one model and ``coded_layers`` setting (default: the spec's),
+        built on first use and cached — compiled step programs,
+        pre-encoded serving weights and warm buckets are reused, so a
+        second serve with the same shapes retraces NOTHING.  ``arch`` is
+        an arch name or a ``ModelConfig``, as in :meth:`serve`."""
+        self._check_open()
+        from ..runtime.serve_loop import ContinuousBatcher
+        model, params, mkey = self._serve_model(arch, tiny, seed)
+        serve_spec = self.spec.serve
+        if coded_layers is None:
+            coded_layers = serve_spec.coded_layers
+        bkey = mkey + (coded_layers, admission)
+        bat = self._serve_batchers.get(bkey)
+        if bat is None:
+            bat = ContinuousBatcher(
+                self.engine, model, params, coded_layers=coded_layers,
+                max_slots=serve_spec.max_slots, eos_id=serve_spec.eos_id,
+                backend=self.spec.transport.backend, admission=admission)
+            self._serve_batchers[bkey] = bat
+        return bat
+
+    def serve(self, arch="qwen2-7b", *, tiny: bool = True,
               batch: Optional[int] = None, prompt_len: int = 16,
               gen: int = 32, seed: int = 0, check_agreement: bool = True,
               requests=None, arrival_rate: float = 0.0,
-              ragged: bool = False,
-              admission: str = "continuous") -> ServeReport:
+              ragged: bool = False, admission: str = "continuous",
+              record_logits: bool = False) -> ServeReport:
         """Continuous-batching greedy decode with every selected
         projection run as coded rounds (``ServeSpec.coded_layers``).
+
+        ``arch`` is an arch name (reduced to its tiny config when
+        ``tiny``) or a ``ModelConfig``, which is served as given — the way
+        a configuration cut to one chip's share reaches the normal path.
 
         Requests are served off an arrival timeline by the scheduler in
         :mod:`repro.runtime.serve_loop`: free slots admit arrivals at
@@ -324,46 +377,31 @@ class Session:
         (0 = all at t=0 — the legacy fixed-batch shape; with a uniform
         workload ``tokens`` is exactly (batch, gen)).
         ``admission="gated"`` reproduces the static-batch baseline.
+        ``record_logits`` keeps every step's logits per request
+        (``ServedRequest.logits``) for parity checks.
         """
         self._check_open()
-        import jax
-        from ..configs import get_config, tiny_config
-        from ..models import build_model
-        from ..runtime.serve_loop import ContinuousBatcher, poisson_workload
+        from ..runtime.serve_loop import poisson_workload
 
-        mkey = (arch, tiny, seed)
-        if mkey not in self._serve_models:
-            cfg = tiny_config(arch) if tiny else get_config(arch)
-            model = build_model(cfg)
-            self._serve_models[mkey] = (model,
-                                        model.init(jax.random.PRNGKey(seed)))
-        model, params = self._serve_models[mkey]
-        cfg = model.cfg
         serve_spec = self.spec.serve
         n_req = batch if batch is not None else serve_spec.max_slots
         if requests is None:
+            model, _, _ = self._serve_model(arch, tiny, seed)
             requests = poisson_workload(
                 n_req, rate_rps=arrival_rate, prompt_len=prompt_len,
-                gen=gen, vocab=cfg.vocab_size, seed=seed, ragged=ragged)
+                gen=gen, vocab=model.cfg.vocab_size, seed=seed,
+                ragged=ragged)
 
-        def run_loop(coded_layers: str):
-            # batchers are cached across serve() calls: compiled step
-            # programs, pre-encoded serving weights and warm buckets are
-            # reused — a second serve with the same shapes retraces NOTHING
-            bkey = mkey + (coded_layers, admission)
-            bat = self._serve_batchers.get(bkey)
-            if bat is None:
-                bat = ContinuousBatcher(
-                    self.engine, model, params, coded_layers=coded_layers,
-                    max_slots=serve_spec.max_slots, eos_id=serve_spec.eos_id,
-                    backend=self.spec.transport.backend, admission=admission)
-                self._serve_batchers[bkey] = bat
+        def run_loop(coded_layers: str, record: bool = False):
+            bat = self.batcher(arch, tiny=tiny, seed=seed,
+                               coded_layers=coded_layers,
+                               admission=admission)
             bat._round = self._round
-            res = bat.run(requests)
+            res = bat.run(requests, record_logits=record)
             self._round = bat._round
             return res
 
-        res = run_loop(serve_spec.coded_layers)
+        res = run_loop(serve_spec.coded_layers, record_logits)
         # token matrix, -1 padded for ragged generation lengths
         max_gen = max((len(r.tokens) for r in res.requests), default=0)
         tokens = np.full((len(res.requests), max_gen), -1, np.int32)

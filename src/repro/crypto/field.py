@@ -470,35 +470,44 @@ def keystream_words_traced_batched(seeds, n_words: int,
     n_ch = seeds.shape[0]
     n_blocks = max(-(-n_words // 4), 1)
     lanes = n_ch * n_blocks
-    lo = jnp.tile(jnp.arange(n_blocks, dtype=jnp.uint32), n_ch)
-    hi = jnp.zeros_like(lo)
-    seed_lanes = tuple(jnp.repeat(seeds[:, i], n_blocks) for i in range(8))
-    w16 = tuple(jnp.broadcast_to(jnp.asarray(w, jnp.uint32), (lanes,))
-                for w in _counter_schedule(seed_lanes, lo, hi, jnp))
+    seeds = jnp.asarray(seeds, jnp.uint32)
     ks = jnp.asarray(_SHA_K)
 
-    if lanes <= lane_chunk:
-        h0 = tuple(jnp.broadcast_to(jnp.uint32(v), (lanes,)) for v in _SHA_H0)
+    def digest_of(lane):
+        # each lane's message schedule is built where it is hashed (lane =
+        # channel * n_blocks + counter): the digest is the only lane-sized
+        # array the keystream materializes
+        seed_lanes = seeds[lane // n_blocks]                 # (L, 8)
+        counter = lane % n_blocks
+        w16 = tuple(jnp.broadcast_to(jnp.asarray(w, jnp.uint32), lane.shape)
+                    for w in _counter_schedule(
+                        [seed_lanes[:, i] for i in range(8)], counter,
+                        jnp.zeros_like(counter), jnp))
+        h0 = tuple(jnp.broadcast_to(jnp.uint32(v), lane.shape)
+                   for v in _SHA_H0)
         carry, _ = jax.lax.scan(_sha_round_step, w16 + h0, ks)
         digest = [v + jnp.uint32(h) for v, h in zip(carry[16:], _SHA_H0)]
+        # digest words pair big-endian into u64 mask words w = d0<<32 | d1,
+        # four per lane, interleaved here: a lane-sized (L, 4) array would
+        # pad its minor dim of 4 to a full 128-wide tile on TPU (32x)
+        return (jnp.stack(digest[1::2], axis=1).reshape(-1),
+                jnp.stack(digest[0::2], axis=1).reshape(-1))
+
+    if lanes <= lane_chunk:
+        word_lo, word_hi = digest_of(jnp.arange(lanes, dtype=jnp.uint32))
     else:
-        pad = -lanes % lane_chunk
-        n_chunks = (lanes + pad) // lane_chunk
-        w16c = tuple(jnp.pad(w, (0, pad)).reshape(n_chunks, lane_chunk)
-                     for w in w16)
-        h0 = tuple(jnp.broadcast_to(jnp.uint32(v), (lane_chunk,))
-                   for v in _SHA_H0)
+        n_chunks = -(-lanes // lane_chunk)
+        base = jnp.arange(lane_chunk, dtype=jnp.uint32)
 
-        def chunk_body(_, w16_chunk):
-            carry, _ = jax.lax.scan(_sha_round_step, w16_chunk + h0, ks)
-            return None, tuple(v + jnp.uint32(h)
-                               for v, h in zip(carry[16:], _SHA_H0))
+        def chunk_body(_, c):
+            # lanes past the end (last chunk) gather a clamped seed and are
+            # sliced off below
+            return None, digest_of(c * jnp.uint32(lane_chunk) + base)
 
-        _, digest = jax.lax.scan(chunk_body, None, w16c)
-        digest = [d.reshape(-1)[:lanes] for d in digest]
-    # digest words pair big-endian into u64 mask words w = d0<<32 | d1
-    word_lo = jnp.stack(digest[1::2], axis=1).reshape(n_ch, -1)
-    word_hi = jnp.stack(digest[0::2], axis=1).reshape(n_ch, -1)
+        _, (word_lo, word_hi) = jax.lax.scan(
+            chunk_body, None, jnp.arange(n_chunks, dtype=jnp.uint32))
+    word_lo = word_lo.reshape(-1)[:4 * lanes].reshape(n_ch, -1)
+    word_hi = word_hi.reshape(-1)[:4 * lanes].reshape(n_ch, -1)
     return word_lo[:, :n_words], word_hi[:, :n_words]
 
 

@@ -37,9 +37,9 @@ __all__ = [
 
 
 def _axis_sizes(mesh) -> dict:
-    """name -> size for anything mesh-shaped (real Mesh or a test double
-    exposing ``axis_names`` and ``devices.shape``)."""
-    return dict(zip(tuple(mesh.axis_names), tuple(mesh.devices.shape)))
+    """name -> size for anything mesh-shaped (``Mesh``, ``AbstractMesh``
+    or a test double exposing ``axis_names`` and ``axis_sizes``)."""
+    return dict(zip(tuple(mesh.axis_names), tuple(mesh.axis_sizes)))
 
 
 def _entry_axes(entry) -> Tuple:
@@ -150,24 +150,13 @@ def tree_add_data_axis(specs, shapes, skip_dims: Iterable[int] = (),
     return jax.tree.unflatten(treedef, out)
 
 
-def _ambient_mesh():
-    """The mesh installed by ``with mesh:`` / ``jax.set_mesh``, or None."""
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except Exception:
-        return None
-    if mesh is None or getattr(mesh, "empty", True):
-        return None
-    return mesh
-
-
 def shard_hint(x, spec):
     """Best-effort layout hint: constrain ``x`` to ``spec`` on the ambient
-    mesh; identity when no mesh is installed (single-device tests) or when
-    the spec names axes the mesh lacks / can't divide."""
-    mesh = _ambient_mesh()
-    if mesh is None:
+    mesh (the one ``jax.set_mesh`` installs); identity when no mesh is
+    installed (single-device tests) or when the spec names axes the mesh
+    lacks / can't divide."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     resolved = resolve_spec(spec, x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, resolved))

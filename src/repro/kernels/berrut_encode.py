@@ -50,6 +50,7 @@ def _kernel(w_ref, b_ref, o_ref, acc_ref, *, n_j_steps: int):
     b = b_ref[...].astype(jnp.float32)          # (bj, bm)
     acc_ref[...] += jax.lax.dot_general(
         w, b, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,      # f32, as ref.berrut_combine
         preferred_element_type=jnp.float32)
 
     @pl.when(j_i == n_j_steps - 1)

@@ -27,8 +27,7 @@ limb parity with ``ops.mea_encrypt_core`` is asserted in
 ``tests/test_encrypted_round.py``.
 
 The bits-codec wire (raw float words in limb 0) admits two exact
-specializations of the general carry-chain mask-add that the hot path
-uses off-TPU (`use_kernel=False`):
+specializations of the general carry-chain mask-add:
 
 * **stream**: payload < 2^32 and mask < 2^64, so payload + mask < 2^65 —
   never reaches a >64-bit modulus and the reduction branch is provably
@@ -42,10 +41,17 @@ uses off-TPU (`use_kernel=False`):
 
 Both specializations are bit-identical to ``crypto.field.add_mod`` /
 ``sub_mod`` (fuzzed against the numpy oracle in tests, adversarial Ψ near
-q included).  With ``use_kernel=True`` the wires run the general Pallas
-``mask_add`` kernel instead (interpret mode off-TPU), and the worker
-matmul runs through the Pallas ``coded_matmul`` kernel with identity
-encode weights.
+q included), and the rounds and the serving step run them on every
+platform: they move 3 limb planes (stream) or one word plus a u8
+selector (paper) where the general carry chain moves all L planes.  On a
+v5e at the paper's fig-3 round (N=30, shards 768×3584) the general Pallas
+``mask_add`` wire needed 12.5 GB of HBM in stream mode and ~130 s to
+compile behind the encode kernel.  ``wire_roundtrip(use_kernel=True)``
+keeps the general kernel path as the parity reference.  With
+``use_kernel=True`` the round's encode and worker matmul run as the two
+halves of the Pallas ``coded_matmul`` kernel (``coded_encode_kernel`` /
+``coded_worker_kernel``), tiled as the fused kernel is, so the encrypted
+round equals the plain kernel round bit for bit.
 
 Retrace policy mirrors the plain fused round: the engine jits one program
 per (a, b) shape class (LRU-cached), and everything per-round — straggler
@@ -267,41 +273,46 @@ def encrypted_coded_matmul(weights, blocks, rhs, material_out, material_back,
     weights (N, J); blocks (J, blk, d); rhs (d, n_out); material_* as in
     :func:`wire_roundtrip` -> (N, blk, n_out) worker results, ready for
     the masked decode.  Because every wire is the lossless bits-codec
-    round trip, the results are bit-identical to ``ref.coded_matmul`` /
-    the staged real path (same contractions, same precision) — asserted in
-    tests.  ``return_wire`` additionally returns the out/back ciphertext
+    round trip, the results are bit-identical to the plain round on the
+    same path: ``ref.coded_matmul`` / the staged real path on the XLA
+    path, the fused ``coded_matmul`` kernel on the kernel path — asserted
+    in tests.  ``return_wire`` additionally returns the out/back ciphertext
     limb planes.
     """
     blocks = jnp.asarray(blocks)
     rhs = jnp.asarray(rhs, jnp.float32)
     weights = jnp.asarray(weights, jnp.float32)
-    flat = blocks.reshape(blocks.shape[0], -1).astype(jnp.float32)
-    coded = jnp.dot(weights, flat, precision=jax.lax.Precision.HIGHEST)
-    coded = coded.reshape((weights.shape[0],) + blocks.shape[1:])
-    # wire out: each worker receives (and decrypts) its coded shard
-    coded, ct_out = (wire_roundtrip(coded, material_out, q=q, mode=mode,
-                                    use_kernel=use_kernel,
-                                    interpret=interpret, return_ct=True)
-                     if return_wire else
-                     (wire_roundtrip(coded, material_out, q=q, mode=mode,
-                                     use_kernel=use_kernel,
-                                     interpret=interpret), None))
     if use_kernel:
-        from .coded_matmul import coded_matmul_kernel
-        eye = jnp.eye(weights.shape[0], dtype=jnp.float32)
-        results = coded_matmul_kernel(eye, coded, rhs, interpret=interpret)
+        # the two halves of the fused coded_matmul kernel, tiled as it is:
+        # the shards and results match the plain kernel round bit for bit
+        from .coded_matmul import coded_encode_kernel, coded_worker_kernel
+        coded = coded_encode_kernel(weights, blocks, n_out=rhs.shape[1],
+                                    interpret=interpret)
+    else:
+        flat = blocks.reshape(blocks.shape[0], -1).astype(jnp.float32)
+        coded = jnp.dot(weights, flat, precision=jax.lax.Precision.HIGHEST)
+        coded = coded.reshape((weights.shape[0],) + blocks.shape[1:])
+    # wire out: each worker receives (and decrypts) its coded shard.  The
+    # wires are the specialized bits-codec wires on every platform (see
+    # the module docstring); ``use_kernel`` picks the matmul kernels only.
+    coded, ct_out = (wire_roundtrip(coded, material_out, q=q, mode=mode,
+                                    return_ct=True)
+                     if return_wire else
+                     (wire_roundtrip(coded, material_out, q=q, mode=mode),
+                      None))
+    if use_kernel:
+        results = coded_worker_kernel(coded, rhs, n_blocks=blocks.shape[0],
+                                      interpret=interpret)
     else:
         results = jnp.einsum("nij,jk->nik", coded, rhs,
                              precision=jax.lax.Precision.HIGHEST)
     # wire back: every worker's product returns encrypted (the straggler
     # slots are computed too — the virtual clock prices who actually ran)
     results, ct_back = (wire_roundtrip(results, material_back, q=q,
-                                       mode=mode, use_kernel=use_kernel,
-                                       interpret=interpret, return_ct=True)
+                                       mode=mode, return_ct=True)
                         if return_wire else
                         (wire_roundtrip(results, material_back, q=q,
-                                        mode=mode, use_kernel=use_kernel,
-                                        interpret=interpret), None))
+                                        mode=mode), None))
     if return_wire:
         return results, ct_out, ct_back
     return results

@@ -84,25 +84,6 @@ def coded_matmul(weights, blocks, rhs, *, force_kernel: bool | None = None):
     return ref.coded_matmul(weights, blocks, rhs)
 
 
-def precoded_matmul(shards, x, weights, *, force_kernel: bool | None = None):
-    """Serving-side coded matmul against PRE-ENCODED weight shards.
-
-    ``shards`` (N, blk, d_in) — ``scheme.encode(W^T)``, resident at the
-    workers; ``x`` (B, d_in) per-step activations; ``weights`` (K, N) —
-    the masked decode matrix of the step's responder set.  Returns the
-    decoded (K, blk, B) row blocks of ``(x @ W)^T``.
-
-    This is the Eq.-23 layout with the encode hoisted out of the round:
-    serving encodes each projection weight once at start-up, so per step
-    only activations move — worker *n* computes ``shards[n] @ x^T`` and
-    the prefix decode is the same :func:`berrut_combine` contraction the
-    per-round path runs.
-    """
-    results = jnp.einsum("nbd,Bd->nbB", jnp.asarray(shards, jnp.float32),
-                         jnp.asarray(x, jnp.float32))
-    return berrut_combine(weights, results, force_kernel=force_kernel)
-
-
 @functools.partial(jax.jit, static_argnames=("q", "use_kernel", "interpret",
                                              "subtract"))
 def _mask_add_impl(payload, mask, *, q, use_kernel, interpret, subtract):
@@ -208,9 +189,10 @@ def encrypted_coded_matmul(weights, blocks, rhs, material_out, material_back,
 
     encode -> MEA-ECC wire-out -> batched worker matmul -> MEA-ECC
     wire-back, one traceable program (see ``kernels.encrypted_round``).
-    ``force_kernel`` is the usual tri-state: None = kernel on TPU only,
-    True = Pallas ``mask_add`` wires + ``coded_matmul`` kernel (interpret
-    mode off-TPU), False = pure XLA with the specialized bits-codec wires.
+    ``force_kernel`` is the usual tri-state for the matmuls: None = kernel
+    on TPU only, True = the two halves of the Pallas ``coded_matmul``
+    kernel (interpret mode off-TPU), False = pure XLA.  The wires are the
+    specialized bits-codec wires either way.
     ``return_wire`` also returns the (N, W, L) out/back ciphertext limb
     planes (parity tests against ``mea_encrypt_core``).
 

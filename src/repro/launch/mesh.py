@@ -1,15 +1,8 @@
-"""Production mesh builders (+ small compat shims for older jax).
+"""Production mesh builders.
 
 Functions, not module-level constants — importing this module never touches
 jax device state (required so smoke tests see 1 device while the dry-run
 sees the 512 placeholder devices it forces before any jax import).
-
-Compat: the dry-run and the multi-device integration tests target the newer
-``jax.set_mesh`` / ``jax.sharding.AxisType`` API.  On the pinned jax
-(0.4.x) those don't exist, so this module exposes :func:`use_mesh` — a
-version-portable ``with use_mesh(mesh):`` that installs the ambient mesh
-``with_sharding_constraint`` resolves bare PartitionSpecs against — and
-omits ``axis_types`` where unsupported.  jax itself is never monkeypatched.
 """
 
 from __future__ import annotations
@@ -21,31 +14,26 @@ __all__ = ["make_production_mesh", "make_test_mesh", "use_mesh", "dp_axes",
 
 
 def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh, portable
-    across jax versions (new: ``jax.set_mesh``; old: Mesh IS a context
-    manager)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh that
+    ``dist.sharding.shard_hint`` and bare-PartitionSpec sharding
+    constraints resolve against."""
+    return jax.set_mesh(mesh)
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto_axes(n_axes: int) -> dict:
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto_axes(len(axes)))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU integration tests (requires forced device count)."""
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, **_auto_axes(len(axes)))
 
 
 def dp_axes(mesh) -> tuple:

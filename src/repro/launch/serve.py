@@ -80,6 +80,8 @@ def main(argv=None):
         coded_layers = "all" if args.transport == "virtual" else "unembed"
 
     from ..api import ClusterSpec, Session
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     spec = ClusterSpec.serve_deadline(
         t_budget=args.deadline_ms * 1e-3, n_workers=args.workers,
         k_blocks=args.k_blocks, n_stragglers=args.stragglers,

@@ -25,6 +25,7 @@ from ..data.pipeline import TokenPipeline
 from ..models import build_model
 from ..optim import adamw, warmup_cosine
 from ..runtime.straggler import StragglerModel
+from .compile_cache import enable_compile_cache
 from .steps import build_train_step
 
 
@@ -52,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = tiny_config(args.arch) if args.tiny else get_config(args.arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))

@@ -17,7 +17,7 @@ projection the :class:`~repro.api.spec.ServeSpec` selects:
 Weights are encoded **once** at serve start (they are what lives on the
 workers); only activations move per step.  All sites of a step share ONE
 straggler plan and ONE decode mask — the whole decode step, every coded
-site included, runs as a single jitted dispatch (``build_coded_step``),
+site included, runs as a single jitted dispatch (``build_coded_logits``),
 with the mask and the per-site wire material (``encrypt="real"``) as
 runtime arguments so admission/eviction churn and responder churn never
 retrigger compilation.
@@ -38,15 +38,20 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
-from ..kernels.ops import berrut_combine, precoded_matmul
+from ..kernels.ops import berrut_combine
 from .layers import apply_norm, dtype_of, embed, unembed
 from .transformer import decode_layer, layer_desc
 
 __all__ = ["SiteMeta", "ServingCode", "layer_sites", "encode_serving_weights",
-           "build_coded_step", "coded_flop_fraction"]
+           "build_coded_logits", "coded_flop_fraction"]
 
 # deterministic site iteration order (material assignment, t_comp sums)
 SITE_ORDER = ("qkv", "o", "up", "down")
+
+# the workers' shard matmuls run at full f32 precision: the shards are
+# f32, and on TPU a default-precision f32 matmul rounds its operands to
+# bf16, an error the decode amplifies (read at trace time)
+SITE_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -259,6 +264,12 @@ def _coded_apply(c, x2d, dec_w, meta: SiteMeta, *, wire=None, mats=None,
     pre-encoded shards; ``x2d`` (B, d_in); ``dec_w`` (K, N) masked Berrut
     decode weights.  Returns (B, d_out) f32.
 
+    This is the Eq.-23 layout with the encode hoisted out of the round:
+    serving encodes each projection weight once at start-up, so per step
+    only activations move — worker *n* computes ``c[n] @ x^T`` (at
+    ``SITE_PRECISION``) and the masked decode is the
+    :func:`berrut_combine` contraction the per-round path runs.
+
     With a wire (``encrypt="real"``), both transfers of the site cross
     the PR 6 one-dispatch cipher: the activations out to every worker
     (each worker gets its own ciphertext of x) and the shard results
@@ -266,14 +277,14 @@ def _coded_apply(c, x2d, dec_w, meta: SiteMeta, *, wire=None, mats=None,
     wired step equals the plain step exactly.
     """
     xf = x2d.astype(jnp.float32)
-    if wire is None:
-        dec = precoded_matmul(c, xf, dec_w, force_kernel=force_kernel)
-    else:
-        xs = jnp.broadcast_to(xf[None], (c.shape[0],) + xf.shape)
+    xs = jnp.broadcast_to(xf[None], (c.shape[0],) + xf.shape)
+    if wire is not None:
         xs = wire(xs, mats[0])
-        results = jnp.einsum("nbd,nBd->nbB", c.astype(jnp.float32), xs)
+    results = jnp.einsum("nbd,nBd->nbB", c.astype(jnp.float32), xs,
+                         precision=SITE_PRECISION)
+    if wire is not None:
         results = wire(results, mats[1])
-        dec = berrut_combine(dec_w, results, force_kernel=force_kernel)
+    dec = berrut_combine(dec_w, results, force_kernel=force_kernel)
     return dec.reshape(-1, x2d.shape[0])[: meta.d_out].T
 
 
@@ -343,35 +354,36 @@ def _layer_proj(cfg: ModelConfig, desc, metas, arrays, dec_w, *, wire=None,
     return proj
 
 
-def build_coded_step(model, scheme, code: ServingCode, *, wire_params=None,
-                     on_trace=None):
-    """The whole-step program: embed → every layer with its projections
-    routed through coded sites → coded unembed → greedy argmax, ONE
-    jitted dispatch per pow2 batch bucket.
+def build_coded_logits(model, scheme, code: ServingCode, *,
+                       wire_params=None):
+    """The whole-step program up to the logits: embed → every layer with
+    its projections routed through coded sites → coded unembed.  The
+    serve loop jits it with a greedy argmax on top, ONE dispatch per pow2
+    batch bucket; the logits themselves are what parity checks compare.
 
-    Returns ``step(params, cache, tokens (B,1), pos (B,), mask (N,),
-    weights, materials) -> (next_tokens (B,), new_cache)``.  ``mask``,
-    ``pos`` and ``materials`` are runtime arguments — responder churn,
-    slot churn inside a bucket and fresh nonces never retrace.
+    Returns ``logits_step(params, cache, tokens (B,1), pos (B,), mask
+    (N,), weights, materials) -> (logits (B, V) f32, new_cache)``.
+    ``mask``, ``pos`` and ``materials`` are runtime arguments — responder
+    churn, slot churn inside a bucket and fresh nonces never retrace.
     """
     cfg = model.cfg
     force_kernel = scheme.use_kernel
     if wire_params is not None:
         q, mode = wire_params
         from ..kernels.encrypted_round import wire_roundtrip
-        kern = bool(force_kernel) if force_kernel is not None else False
 
+        # the specialized bits-codec wires on every platform, as in the
+        # encrypted rounds (see kernels.encrypted_round): the general
+        # Pallas mask_add carry chain moves all L limb planes where these
+        # move 3 (stream) or 1 plus a selector byte (paper)
         def wire(payload, mat):
-            return wire_roundtrip(payload, mat, q=q, mode=mode,
-                                  use_kernel=kern)
+            return wire_roundtrip(payload, mat, q=q, mode=mode)
     else:
         wire = None
 
     use_wire = wire is not None
 
-    def step(params, cache, tokens, pos, mask, weights, materials):
-        if on_trace is not None:
-            on_trace()                         # runs at trace time only
+    def logits_step(params, cache, tokens, pos, mask, weights, materials):
         dec_w = scheme.decode_matrix_masked(mask)          # (K, N)
         x = embed(params["embedding"], tokens, cfg)
         new_pre = []
@@ -417,10 +429,11 @@ def build_coded_step(model, scheme, code: ServingCode, *, wire_params=None,
                     logits / cfg.logit_softcap)
         else:
             logits = unembed(params["embedding"], x, cfg)[:, 0, :]
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_tok, {"prelude": new_pre, "groups": new_groups}
+        return (logits.astype(jnp.float32),
+                {"prelude": new_pre, "groups": new_groups})
 
-    return step
+    return logits_step
+
 
 
 # --------------------------------------------------------------------------

@@ -330,6 +330,7 @@ class RoundEngine:
         # reuses ITS compiled functions instead of tracing fresh ones —
         # retuning cycles the LRU, it never recompiles per round
         self._scheme_token = ("base",)
+        self._timed_rounds: set = set()     # (token, shapes) already run
         self.adaptive = None
         ad = getattr(spec, "adaptive", None)
         if ad is not None and ad.enabled:
@@ -465,15 +466,11 @@ class RoundEngine:
             from ..kernels.encrypted_round import wire_roundtrip
             mode = self._mea.mode
             q = self._mea.curve.q
-            kern = bool(self.scheme.use_kernel) \
-                if self.scheme.use_kernel is not None else False
             mat_out, mat_back = self._fused_mask_material()
 
             def _wires(x_out, x_back, mo, mb):
-                return (wire_roundtrip(x_out, mo, q=q, mode=mode,
-                                       use_kernel=kern),
-                        wire_roundtrip(x_back, mb, q=q, mode=mode,
-                                       use_kernel=kern))
+                return (wire_roundtrip(x_out, mo, q=q, mode=mode),
+                        wire_roundtrip(x_back, mb, q=q, mode=mode))
 
             fn = jax.jit(_wires)
             args = (jnp.zeros((self.n, blk, d), jnp.float32),
@@ -1185,16 +1182,24 @@ class RoundEngine:
         self.fh_degree = dec.fh_degree
         self.wait_for = self.scheme.wait_policy(self.straggler.n_stragglers)
 
-    def _adaptive_observe(self, round_idx: int, stats: RoundStats) -> None:
+    def _adaptive_observe(self, round_idx: int, stats: RoundStats,
+                          shapes) -> None:
         """Feed the round's consumed arrivals back to the estimator and
         the health tracker.  Only the consumed prefix is observed — the
         real transports never see past what the policy waited for, so
         observing the virtual clock's full timeline would make the two
-        transports fit different models from the same trace."""
+        transports fit different models from the same trace.  The first
+        round of a scheme at given shapes compiles its worker programs,
+        and on real transports that compile lands in the arrival times:
+        its baseline is no compute measurement, so no transport feeds
+        it."""
         consumed = tuple(stats.arrivals[: max(stats.n_waited, 1)])
+        key = (self._scheme_token,) + tuple(shapes)
+        timed = key in self._timed_rounds
+        self._timed_rounds.add(key)
         self.adaptive.observe(round_idx, consumed,
                               k_blocks=int(getattr(self.scheme, "k_blocks",
-                                                   self.k)))
+                                                   self.k)) if timed else None)
         if self.health is not None and not self.fault.active:
             for t, w in consumed:
                 self.health.record_ok(int(w), float(t))
@@ -1215,7 +1220,8 @@ class RoundEngine:
         if self.adaptive is not None:
             self._adaptive_retune(round_idx)
             out, stats = self._matmul_inner(a, b, round_idx)
-            self._adaptive_observe(round_idx, stats)
+            self._adaptive_observe(round_idx, stats,
+                                   (np.shape(a), np.shape(b)))
             return out, stats
         return self._matmul_inner(a, b, round_idx)
 

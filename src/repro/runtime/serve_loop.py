@@ -17,7 +17,7 @@ machinery:
   ``trace_count`` is asserted flat in the tests;
 * **one coded round per step** — on the virtual transport every selected
   projection of every in-flight request runs inside ONE jitted step
-  program (``models.coded.build_coded_step``) under ONE straggler plan
+  program (``models.coded.build_coded_logits``) under ONE straggler plan
   and ONE decode mask per step, the spec's wait policy choosing the
   responder prefix.
 
@@ -37,12 +37,12 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
 __all__ = ["Request", "ServedRequest", "ServeResult", "poisson_workload",
-           "ContinuousBatcher"]
+           "ContinuousBatcher", "logit_gap"]
 
 
 @dataclasses.dataclass
@@ -64,6 +64,10 @@ class ServedRequest:
     done_s: float
     n_prompt: int
     tokens: np.ndarray               # (gen'd,) int32
+    # (n_prompt - 1 + gen'd, V) f32 logits the slot read at each of its
+    # steps (``run(record_logits=True)``): row j follows feeding the j-th
+    # token of ``prompt + tokens[:-1]``
+    logits: Optional[np.ndarray] = None
 
     @property
     def ttft_s(self) -> float:
@@ -157,6 +161,7 @@ class _Slot:
     last_tok: int = 0
     first_token_s: float = float("nan")
     tokens: List[int] = dataclasses.field(default_factory=list)
+    logits: list = dataclasses.field(default_factory=list)
     done: bool = False               # gated mode: finished but slot-bound
 
 
@@ -176,7 +181,7 @@ class ContinuousBatcher:
       still continuously batched (the uncoded baseline);
     * virtual transport + a fused-capable scheme → **instep**: the whole
       step (all selected coded sites) is one jitted dispatch
-      (``build_coded_step``), priced by one straggler plan per step;
+      (``build_coded_logits``), priced by one straggler plan per step;
     * real transports (threads/socket) → **round**: the PR 5 semantics —
       hidden state on the master, the unembed projection as one real
       ``engine.matmul`` round per step (spec validation already restricts
@@ -221,31 +226,30 @@ class ContinuousBatcher:
                 f"backend={backend!r} supports_fused={supports_fused}")
 
         cfg = model.cfg
-
-        def bump():
-            self.trace_count += 1            # runs at trace time only
-
+        # ``_forward`` is the step program up to what the host reads: the
+        # (B, V) f32 logits, or — round mode — the (B, d) f32 hidden state
+        # whose unembed is the real coded round.  The jitted ``_step`` adds
+        # the greedy argmax and returns the logits it read alongside.
         if self.mode == "instep":
-            from ..models.coded import (build_coded_step, coded_flop_fraction,
+            from ..models.coded import (build_coded_logits,
+                                        coded_flop_fraction,
                                         encode_serving_weights)
             self.code = encode_serving_weights(engine.scheme, model, params,
                                                coded_layers)
             self.wire_params = engine.serve_wire_params()
-            self._step = jax.jit(build_coded_step(
-                model, engine.scheme, self.code,
-                wire_params=self.wire_params, on_trace=bump))
+            self._forward = build_coded_logits(
+                model, engine.scheme, self.code, wire_params=self.wire_params)
             self.coded_fraction = coded_flop_fraction(cfg, coded_layers)
             self._t_comp: Dict[int, float] = {}
         elif self.mode == "round":
             from ..models.coded import coded_flop_fraction
 
             def hidden(params, cache, tokens, pos):
-                bump()
                 h, nc = model.decode_step(params, cache, tokens, pos,
                                           return_hidden=True)
                 return h[:, 0, :].astype(jnp.float32), nc
 
-            self._step = jax.jit(hidden)
+            self._forward = hidden
             emb = params["embedding"]
             self._wt = np.asarray(emb["table"] if cfg.tie_embeddings
                                   else emb["unembed"].T, np.float32)
@@ -253,13 +257,20 @@ class ContinuousBatcher:
         else:
 
             def plain(params, cache, tokens, pos):
-                bump()
                 logits, nc = model.decode_step(params, cache, tokens, pos)
-                nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
-                return nxt, nc
+                return logits[:, 0, :].astype(jnp.float32), nc
 
-            self._step = jax.jit(plain)
+            self._forward = plain
             self.coded_fraction = 0.0
+
+        def step(*args):
+            self.trace_count += 1            # runs at trace time only
+            out, nc = self._forward(*args)
+            if self.mode == "round":
+                return out, nc
+            return jnp.argmax(out, axis=-1).astype(jnp.int32), out, nc
+
+        self._step = jax.jit(step)
         self._warm: set = set()              # buckets already compiled
 
     # ---------------------------------------------------------- cache ops
@@ -322,7 +333,8 @@ class ContinuousBatcher:
         return out, time.perf_counter() - t0
 
     def _run_step(self, cache, tok, pos, b):
-        """One step at bucket ``b``: returns (next_tokens (b,), new cache,
+        """One step at bucket ``b``: returns (next_tokens (b,), logits
+        (b, V) — on the device, except in round mode — new cache,
         RoundStats, virtual_dur_s, wall_s)."""
         jnp = self._jnp
         from .engine import RoundStats
@@ -334,12 +346,12 @@ class ContinuousBatcher:
                                                self._site_t_comp(b))
             self._round += 1
             crypto = 0.0
-            mats: Any = {}
+            mats = {}
             if self.wire_params is not None:
                 mats = self.code.step_materials(self.engine)
                 crypto = self.engine.serve_crypto_time(
                     *self.code.wire_elems(b))
-            (nxt, new_cache), wall = self._timed(
+            (nxt, logits, new_cache), wall = self._timed(
                 b, self.params, sliced, tok_a, pos_a,
                 jnp.asarray(plan.mask), self.code.arrays, mats)
             self.engine.dispatch_count += 1
@@ -356,20 +368,25 @@ class ContinuousBatcher:
                                              round_idx=self._round)
             wall += time.perf_counter() - t0
             self._round += 1
-            nxt = np.asarray(prod).T.argmax(-1).astype(np.int32)
+            logits = np.asarray(prod).T
+            nxt = logits.argmax(-1).astype(np.int32)
             virt = stats.total_s
         else:
-            (nxt, new_cache), wall = self._timed(b, self.params, sliced,
-                                                 tok_a, pos_a)
+            (nxt, logits, new_cache), wall = self._timed(
+                b, self.params, sliced, tok_a, pos_a)
             stats = RoundStats(encode_s=wall, compute_wait_s=0.0,
                                decode_s=0.0, policy="uncoded", dispatches=1)
             virt = wall
         cache = self._merge_cache(cache, new_cache, b)
-        return np.asarray(nxt), cache, stats, virt, wall
+        return np.asarray(nxt), logits, cache, stats, virt, wall
 
     # --------------------------------------------------------------- loop
-    def run(self, requests: Sequence[Request]) -> ServeResult:
-        jnp = self._jnp
+    def run(self, requests: Sequence[Request], *,
+            record_logits: bool = False) -> ServeResult:
+        """Serve ``requests`` to completion.  ``record_logits`` copies
+        each step's logits to the host and returns every request's rows
+        in :attr:`ServedRequest.logits` — what parity checks compare,
+        since greedy tokens flip on near-ties."""
         reqs = sorted(requests, key=lambda r: (r.arrival_s, r.rid))
         max_len = max(len(r.prompt) + r.gen for r in reqs) + 1
         cache = self.model.init_cache(self.max_slots, max_len)
@@ -414,7 +431,13 @@ class ContinuousBatcher:
                 pos[i] = s.fed
             tok[len(slots):b] = 0                # padded slots: ignored rows
             pos[len(slots):b] = 0
-            nxt, cache, stats, virt, wall = self._run_step(cache, tok, pos, b)
+            nxt, logits, cache, stats, virt, wall = self._run_step(
+                cache, tok, pos, b)
+            if record_logits:
+                logits = np.asarray(logits, np.float32)
+                for i, s in enumerate(slots):
+                    if not s.done:
+                        s.logits.append(logits[i])
             busy += wall
             t_v += virt
             step_stats.append(stats)
@@ -441,7 +464,9 @@ class ContinuousBatcher:
                             admitted_s=s.admitted_s,
                             first_token_s=s.first_token_s, done_s=t_v,
                             n_prompt=plen,
-                            tokens=np.asarray(s.tokens, np.int32)))
+                            tokens=np.asarray(s.tokens, np.int32),
+                            logits=(np.stack(s.logits) if record_logits
+                                    else None)))
                         finished.append(i)
                 s.fed += 1
             if self.admission == "gated":
@@ -462,3 +487,36 @@ class ContinuousBatcher:
             buckets=np.asarray(bucket_log, np.int64), busy_wall_s=busy,
             virtual_s=t_v, trace_count=self.trace_count, mode=self.mode,
             coded_fraction=self.coded_fraction)
+
+
+def logit_gap(served: Sequence[ServedRequest],
+              reference: Sequence[ServedRequest]) -> Dict[str, float]:
+    """Compare the recorded logits of two serves of one workload (both
+    run with ``record_logits=True``), request by request.
+
+    A row is compared while both serves fed the request the same tokens:
+    the whole prompt, then generated tokens up to and including the step
+    that produced the first token they disagree on (after it the two
+    continuations are different inputs).  Returns the largest absolute
+    logit difference (``max_abs_diff``), the largest absolute reference
+    logit over the compared rows (``scale``), the rows compared and the
+    rows recorded (``rows``, ``rows_total``) and the share of generated
+    tokens that agree (``token_agreement``)."""
+    err = scale = 0.0
+    rows = rows_total = match = total = 0
+    for a, b in zip(served, reference):
+        if a.rid != b.rid:
+            raise ValueError(f"request {a.rid} compared with {b.rid}")
+        n = min(len(a.tokens), len(b.tokens))
+        differ = np.flatnonzero(a.tokens[:n] != b.tokens[:n])
+        k = a.n_prompt - 1 + (int(differ[0]) + 1 if differ.size else n)
+        if k:
+            err = max(err, float(np.abs(a.logits[:k] - b.logits[:k]).max()))
+            scale = max(scale, float(np.abs(b.logits[:k]).max()))
+        rows += k
+        rows_total += len(a.logits)
+        match += int(np.sum(a.tokens[:n] == b.tokens[:n]))
+        total += max(len(a.tokens), len(b.tokens))
+    return {"max_abs_diff": err, "scale": scale, "rows": rows,
+            "rows_total": rows_total,
+            "token_agreement": match / max(total, 1)}

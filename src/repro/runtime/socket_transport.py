@@ -278,8 +278,9 @@ class SocketTransport:
         # namespace package: resolve the import root off __path__
         pkg_root = str(Path(next(iter(repro.__path__))).resolve().parent)
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
-        # N extra jax runtimes on one host: CPU only, quiet logs
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # workers are CPU processes by design: whatever the parent exports,
+        # they must never open the accelerator the parent holds
+        env["JAX_PLATFORMS"] = "cpu"
         cmd = [self.python or sys.executable, "-m", "repro.launch.worker",
                "--connect", f"{self.host}:{self.port}",
                "--worker-id", str(wid),

@@ -13,10 +13,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:    # container without hypothesis: deterministic fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.api import (ClusterSpec, CodeSpec, CryptoSpec, FaultSpec,
                        PrivacySpec, Session, StragglerSpec, TransportSpec,
@@ -352,21 +349,35 @@ def test_worker_health_ranked_prefers_fast_workers():
 
 # ------------------------------------------- transport satellites (a + b)
 
+def _failing_straggler():
+    """Worker 1 starts, then fails only once ``release`` is set: the round
+    can be finished while it is known to be running, however loaded the
+    host (a straggler still queued at ``finish()`` is cancelled, not
+    failed)."""
+    started, release = threading.Event(), threading.Event()
+
+    def f(x):
+        if x == 1:
+            started.set()
+            release.wait(5.0)
+            raise RuntimeError("boom")
+        return x
+
+    return f, started, release
+
+
 def test_stray_failure_tagged_with_originating_round():
     tr = ThreadTransport(2, StragglerModel(n_workers=2, n_stragglers=0,
                                            seed=0, delay_s=0.0))
     try:
-        def f(x):
-            if x == 1:
-                time.sleep(0.15)
-                raise RuntimeError("boom")
-            return x
-
+        f, started, release = _failing_straggler()
         h = tr.submit_round([0, 1], f, round_idx=5, t_compute=1e-4)
         it = h.events()
         ev = next(it)           # consume the healthy worker only
         assert ev.worker == 0
+        assert started.wait(5.0)
         h.finish()              # straggler still running: no error yet
+        release.set()
         time.sleep(0.4)         # let the failure land
         with pytest.raises(RuntimeError,
                            match=r"originating round 5") as ei:
@@ -380,15 +391,12 @@ def test_stray_failure_still_surfaces_on_next_submit():
     tr = ThreadTransport(2, StragglerModel(n_workers=2, n_stragglers=0,
                                            seed=0, delay_s=0.0))
     try:
-        def f(x):
-            if x == 1:
-                time.sleep(0.15)
-                raise RuntimeError("boom")
-            return x
-
+        f, started, release = _failing_straggler()
         h = tr.submit_round([0, 1], f, round_idx=3, t_compute=1e-4)
         next(h.events())
+        assert started.wait(5.0)
         h.finish()
+        release.set()
         time.sleep(0.4)
         with pytest.raises(RuntimeError, match=r"originating round 3"):
             tr.submit_round([0, 1], lambda x: x, 4, t_compute=1e-4)

@@ -3,10 +3,7 @@
 import numpy as np
 import jax
 import jax.numpy as jnp
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:    # container without hypothesis: deterministic fallback
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import SPACDCCode, SPACDCConfig, berrut, pad_to_blocks
 from repro.crypto.mea_ecc import FixedPointCodec

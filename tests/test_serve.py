@@ -5,21 +5,23 @@ semantics, ServeSpec validation, and the report's latency accounting."""
 import dataclasses
 import math
 
+import jax
 import numpy as np
 import pytest
 
 from repro.api import (ClusterSpec, CodeSpec, CryptoSpec, PrivacySpec,
                        ServeSpec, Session, StragglerSpec, TransportSpec,
                        WaitSpec)
-from repro.runtime.serve_loop import (ContinuousBatcher, Request,
+from repro.configs import tiny_config
+from repro.runtime.serve_loop import (ContinuousBatcher, Request, logit_gap,
                                       poisson_workload)
 
 
 def exact_spec(coded_layers="all", *, backend="virtual", max_slots=4,
                eos_id=None, crypto=None):
-    """MDS + wait-for-all + no stragglers: the decode is EXACT (linear
-    Vandermonde inversion), so coded greedy tokens must be bit-identical
-    to the plain path — the parity configurations."""
+    """MDS + wait-for-all + no stragglers: the decode is exact in exact
+    arithmetic (linear Vandermonde inversion), so coded logits match the
+    plain path's within ``LOGIT_RTOL`` — the parity configurations."""
     kw = dict(code=CodeSpec(scheme="mds", n_workers=8, k_blocks=4),
               wait=WaitSpec(policy="first_k", k=8),
               straggler=StragglerSpec(n_stragglers=0),
@@ -52,56 +54,97 @@ def serve_tokens(spec, requests, **kw):
 
 
 # --------------------------------------------------------------------------
-# parity: coded == uncoded, token for token
+# parity: coded logits == uncoded logits, within stated tolerances
 # --------------------------------------------------------------------------
+
+# Parity runs the model at compute float32 and full matmul precision in
+# both arms, so the only difference left is the coded path itself: f32
+# shard matmuls and the f32 inverse of the responders' Vandermonde rows
+# (K=4, condition ~10).  That is 1.4e-6 of the logit scale for every
+# coded_layers setting at the tiny config on CPU (1.7e-5 on the threads
+# transport, whose unembed is a host round); a decode that dropped or
+# mis-weighted a worker is off by O(1) of the scale, and shard matmuls at
+# bf16 operand precision (2^-9) by ~3e-3 — both far outside this bound.
+LOGIT_RTOL = 1e-4
+
+
+def parity_gap(spec, requests, arch="qwen2-7b"):
+    """Serve ``requests`` (a ragged continuous batch) through the spec's
+    coded step and through the uncoded step of the same session (same
+    weights), both at compute float32 and full precision, recording every
+    step's logits; returns the coded report and the
+    :func:`~repro.runtime.serve_loop.logit_gap` between the two."""
+    cfg = dataclasses.replace(tiny_config(arch), compute_dtype="float32")
+    with jax.default_matmul_precision("highest"), Session(spec) as s:
+        rep = s.serve(cfg, requests=requests, check_agreement=False,
+                      record_logits=True)
+        ref = s.batcher(cfg, coded_layers="none").run(requests,
+                                                      record_logits=True)
+    return rep, logit_gap(rep.requests, ref.requests)
+
+
+def assert_parity(rep, gap):
+    assert all(np.isfinite(r.logits).all() for r in rep.requests)
+    # every slot is compared at least over its prefill and first token
+    assert gap["rows"] >= sum(r.n_prompt for r in rep.requests)
+    assert gap["max_abs_diff"] <= LOGIT_RTOL * gap["scale"], gap
+
 
 class TestCodedServeParity:
     @pytest.mark.parametrize("coded_layers",
                              ["unembed", "attn", "ffn", "all"])
     def test_tokens_bit_identical_across_coded_layers(self, coded_layers):
-        reqs = ragged_requests(n=5)
-        ref = serve_tokens(exact_spec("none"), reqs)
-        rep = serve_tokens(exact_spec(coded_layers), reqs)
+        # the name predates the tolerance: tokens flip on near-ties when
+        # the reduction order changes, so the logits are what is compared
+        rep, gap = parity_gap(exact_spec(coded_layers), ragged_requests(n=5))
         assert rep.mode == "instep"
-        np.testing.assert_array_equal(ref.tokens, rep.tokens)
+        assert len(rep.requests) == 5
+        assert_parity(rep, gap)
 
     def test_parity_holds_on_mla_arch(self):
         # deepseek: MLA qkv/o sites + dense-FFN positions of the MoE stack
-        reqs = ragged_requests(n=3, seed=5)
-        with Session(exact_spec("none")) as s:
-            ref = s.serve(arch="deepseek-v2-lite-16b", tiny=True,
-                          requests=reqs, check_agreement=False)
-        with Session(exact_spec("all")) as s:
-            rep = s.serve(arch="deepseek-v2-lite-16b", tiny=True,
-                          requests=reqs, check_agreement=False)
-        np.testing.assert_array_equal(ref.tokens, rep.tokens)
+        assert_parity(*parity_gap(exact_spec("all"),
+                                  ragged_requests(n=3, seed=5),
+                                  arch="deepseek-v2-lite-16b"))
 
     def test_parity_with_real_encryption(self):
         # encrypt="real": every site's two transfers cross the one-dispatch
         # cipher in-step; the bits codec keeps the round trip lossless, so
-        # tokens stay bit-identical and crypto time is attributed
+        # the wired logits EQUAL the unwired ones and crypto time is
+        # attributed
         reqs = ragged_requests(n=4)
-        ref = serve_tokens(exact_spec("none"), reqs)
-        rep = serve_tokens(
+        wired, gap = parity_gap(
             exact_spec("all", crypto=CryptoSpec(encrypt="real")), reqs)
-        np.testing.assert_array_equal(ref.tokens, rep.tokens)
-        assert all(st.crypto_s > 0 for st in rep.step_stats)
-        assert all(st.dispatches == 1 for st in rep.step_stats)
+        unwired, _ = parity_gap(exact_spec("all"), reqs)
+        for a, b in zip(wired.requests, unwired.requests):
+            np.testing.assert_array_equal(a.logits, b.logits)
+        assert_parity(wired, gap)
+        assert all(st.crypto_s > 0 for st in wired.step_stats)
+        assert all(st.dispatches == 1 for st in wired.step_stats)
 
     def test_parity_on_threads_transport(self):
         # real transports keep the PR 5 semantics: unembed as a real round
-        reqs = ragged_requests(n=3)
-        ref = serve_tokens(exact_spec("none"), reqs)
-        rep = serve_tokens(exact_spec("unembed", backend="threads"), reqs)
+        rep, gap = parity_gap(exact_spec("unembed", backend="threads"),
+                              ragged_requests(n=3))
         assert rep.mode == "round"
-        np.testing.assert_array_equal(ref.tokens, rep.tokens)
+        assert_parity(rep, gap)
 
     def test_session_agreement_diagnostic(self):
         # the built-in diagnostic replays the workload uncoded and compares
+        # token for token: it must equal the agreement of a separate
+        # coded serve with a separate uncoded serve of the same workload
+        # (greedy tokens depend only on the request's own prompt).  Exact
+        # codes agree on all but near-tie tokens.
+        reqs = ragged_requests(n=3)
         with Session(exact_spec("all")) as s:
-            rep = s.serve(arch="qwen2-7b", tiny=True,
-                          requests=ragged_requests(n=3))
-        assert rep.argmax_agreement == 1.0
+            rep = s.serve(arch="qwen2-7b", tiny=True, requests=reqs)
+        coded = serve_tokens(exact_spec("all"), reqs).requests
+        plain = serve_tokens(exact_spec("none"), reqs).requests
+        match = sum(int(np.sum(a.tokens == b.tokens))
+                    for a, b in zip(coded, plain))
+        total = sum(len(a.tokens) for a in coded)
+        assert rep.argmax_agreement == match / total
+        assert rep.argmax_agreement >= 0.75
 
     def test_spacdc_deadline_agreement_is_bounded_not_exact(self):
         # the paper's own scheme is APPROXIMATED coded computing: under a

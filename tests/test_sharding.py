@@ -10,10 +10,7 @@ from repro.launch.hlo_analysis import analyze, _parse_computations
 
 class FakeMesh:
     axis_names = ("data", "model")
-
-    class _Dev:
-        shape = (4, 2)
-    devices = _Dev()
+    axis_sizes = (4, 2)
 
 
 def test_prune_spec_drops_nondivisible():
@@ -93,3 +90,23 @@ def test_hlo_parser_counts_computations():
     comps, entry = _parse_computations(SYNTH_HLO)
     assert entry == "main"
     assert set(comps) == {"body", "cond", "add", "main"}
+
+
+def test_shard_hint_constrains_under_use_mesh():
+    # the mesh jax.set_mesh installs is the one shard_hint resolves against:
+    # a constraint inside the context, the identity outside it
+    from repro.dist.sharding import shard_hint
+    from repro.launch.mesh import use_mesh
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+    def hinted(x):
+        return shard_hint(x, P("data", "model")) * 2
+
+    x = np.ones((4, 8), np.float32)
+    with use_mesh(mesh):
+        inside = str(jax.make_jaxpr(hinted)(x))
+        out = jax.jit(hinted)(x)
+    assert "sharding_constraint" in inside
+    assert "sharding_constraint" not in str(jax.make_jaxpr(hinted)(x))
+    np.testing.assert_array_equal(np.asarray(out), 2 * x)
