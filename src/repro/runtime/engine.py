@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 import time
 from typing import Optional
@@ -48,6 +49,7 @@ import jax.numpy as jnp
 from .faults import (DegradedRoundError, FaultInjectingTransport,
                      ResultDropped, WorkerHealth, retry_round_index,
                      _BACKOFF_STREAM)
+from .spans import span
 from .scheduler import (EncodePipeline, assemble_curve, plan_round,
                         retry_backoff, screen_responders, virtual_events)
 from .tasks import (EnvelopeMatmulTask, MatmulTask, PairMatmulTask,
@@ -483,6 +485,18 @@ class RoundEngine:
         return self._fused_crypto_t[key]
 
     # ------------------------------------------------------- fused pipeline
+    def _jit(self, fn, name: str):
+        """``jax.jit`` of one round program: each trace bumps
+        ``trace_count`` and is marked by a ``spacdc.trace`` span, so a
+        dispatch that recompiled shows in the profiler's trace.  The
+        program keeps ``fn``'s name (``jit_<fn.__name__>``)."""
+        @functools.wraps(fn)
+        def traced(*args):
+            self.trace_count += 1          # runs at trace time only
+            with span("trace", fn=name):
+                return fn(*args)
+        return jax.jit(traced)
+
     def _fused_fn(self, a_shape, b_shape, dtype):
         """The jitted round for one shape class, LRU-cached.  The straggler
         mask is a traced argument, so responder churn never recompiles."""
@@ -493,11 +507,10 @@ class RoundEngine:
             m, n_out = a_shape[0], b_shape[-1]
 
             def _round(a, b, mask):
-                self.trace_count += 1      # runs at trace time only
                 decoded = scheme.fused_round(a, b, mask)
                 return scheme.reconstruct_matmul(decoded, m, n_out)
 
-            fn = jax.jit(_round)
+            fn = self._jit(_round, "fused_round")
             self._fused_cache[key] = fn
             if len(self._fused_cache) > self._fused_cache_max:
                 self._fused_cache.popitem(last=False)
@@ -519,23 +532,22 @@ class RoundEngine:
             m, n_out = a_shape[0], b_shape[-1]
 
             def _encode(a):
-                self.trace_count += 1      # runs at trace time only
                 return scheme.encode(a)
 
             def _workers(blocks, b):
-                self.trace_count += 1
                 return jnp.einsum(
                     "nij,jk->nik", blocks.astype(jnp.float32),
                     b.astype(jnp.float32),
                     precision=jax.lax.Precision.HIGHEST).astype(jnp.float32)
 
             def _decode(results, mask):
-                self.trace_count += 1
                 dec = scheme._combine(scheme.decode_matrix_masked(mask),
                                       results)
                 return scheme.reconstruct_matmul(dec, m, n_out)
 
-            fns = (jax.jit(_encode), jax.jit(_workers), jax.jit(_decode))
+            fns = (self._jit(_encode, "staged_encode"),
+                   self._jit(_workers, "staged_workers"),
+                   self._jit(_decode, "staged_decode"))
             self._fused_cache[key] = fns
             if len(self._fused_cache) > self._fused_cache_max:
                 self._fused_cache.popitem(last=False)
@@ -563,7 +575,6 @@ class RoundEngine:
             q, mode = self._mea.curve.q, self._mea.mode
 
             def _round(a, b, mask, mat_out, mat_back):
-                self.trace_count += 1      # runs at trace time only
                 results = encrypted_coded_matmul(
                     enc, scheme.fused_blocks(a), b, mat_out, mat_back,
                     q=q, mode=mode, force_kernel=scheme.use_kernel)
@@ -571,7 +582,7 @@ class RoundEngine:
                                       results)
                 return scheme.reconstruct_matmul(dec, m, n_out)
 
-            fn = jax.jit(_round)
+            fn = self._jit(_round, "real_fused_round")
             self._fused_cache[key] = fn
             if len(self._fused_cache) > self._fused_cache_max:
                 self._fused_cache.popitem(last=False)
@@ -610,10 +621,11 @@ class RoundEngine:
         wait policy consumes.  Shared by the fused and real-encryption
         paths so their responder selection can never desynchronize (the
         real round is asserted bit-identical to the unencrypted one)."""
-        blk, t_comp = self._round_compute_time(a_shape, b_shape)
-        plan = plan_round(self.scheme, self.policy,
-                          self.straggler.delays(round_idx), t_comp,
-                          self.straggler.n_stragglers, proxy_fn=proxy_fn)
+        with span("round.plan"):
+            blk, t_comp = self._round_compute_time(a_shape, b_shape)
+            plan = plan_round(self.scheme, self.policy,
+                              self.straggler.delays(round_idx), t_comp,
+                              self.straggler.n_stragglers, proxy_fn=proxy_fn)
         return blk, plan
 
     # ------------------------------------------------------------- serving
@@ -717,9 +729,11 @@ class RoundEngine:
         blk, plan = self._virtual_round_plan(a.shape, b.shape, round_idx)
         # master math (encode + decode + reassembly): one dispatch
         t0 = time.perf_counter()
-        out = fn(a, b, jnp.asarray(plan.mask))
+        with span("round.dispatch"):
+            out = fn(a, b, jnp.asarray(plan.mask))
         self.dispatch_count += 1
-        jax.block_until_ready(out)
+        with span("round.wait"):
+            jax.block_until_ready(out)
         t_master = time.perf_counter() - t0
         crypto_s = self._crypto_overhead_elems(self.n * blk * a.shape[1],
                                                np.float32)
@@ -731,7 +745,9 @@ class RoundEngine:
                             dispatches=1,
                             pipelined_s=self._account_encode(hideable,
                                                              plan.wait_s))
-        return np.asarray(out), stats
+        with span("round.to_host"):
+            host = np.asarray(out)
+        return host, stats
 
     def _matmul_real_fused(self, a: jnp.ndarray, b: jnp.ndarray,
                            round_idx: int):
@@ -748,10 +764,12 @@ class RoundEngine:
         blk, plan = self._virtual_round_plan(a.shape, b.shape, round_idx)
         mat_out, mat_back = self._fused_mask_material()
         t0 = time.perf_counter()
-        out = fn(a, b, jnp.asarray(plan.mask), jnp.asarray(mat_out),
-                 jnp.asarray(mat_back))
+        with span("round.dispatch"):
+            out = fn(a, b, jnp.asarray(plan.mask), jnp.asarray(mat_out),
+                     jnp.asarray(mat_back))
         self.dispatch_count += 1
-        jax.block_until_ready(out)
+        with span("round.wait"):
+            jax.block_until_ready(out)
         t_master = time.perf_counter() - t0
         crypto_s = min(self._fused_crypto_time(blk, a.shape[1], b.shape[-1]),
                        t_master)
@@ -766,7 +784,9 @@ class RoundEngine:
                             crypto_modeled_s=modeled, dispatches=1,
                             pipelined_s=self._account_encode(hideable,
                                                              plan.wait_s))
-        return np.asarray(out), stats
+        with span("round.to_host"):
+            host = np.asarray(out)
+        return host, stats
 
     def _staged_stage1(self, a, b, enc_fn, worker_fn):
         """Encode, wire every coded shard to its worker (MEA-ECC), run the
@@ -853,11 +873,10 @@ class RoundEngine:
             enc = jnp.asarray(scheme.fused_encoder_matrix(), jnp.float32)
 
             def _results(a, b):
-                self.trace_count += 1      # runs at trace time only
                 return coded_matmul(enc, scheme.fused_blocks(a), b,
                                     force_kernel=scheme.use_kernel)
 
-            fn = jax.jit(_results)
+            fn = self._jit(_results, "anytime_results")
             self._fused_cache[key] = fn
             if len(self._fused_cache) > self._fused_cache_max:
                 self._fused_cache.popitem(last=False)
@@ -882,12 +901,11 @@ class RoundEngine:
             q, mode = self._mea.curve.q, self._mea.mode
 
             def _results(a, b, mat_out, mat_back):
-                self.trace_count += 1      # runs at trace time only
                 return encrypted_coded_matmul(
                     enc, scheme.fused_blocks(a), b, mat_out, mat_back,
                     q=q, mode=mode, force_kernel=scheme.use_kernel)
 
-            fn = jax.jit(_results)
+            fn = self._jit(_results, "anytime_results_real")
             self._fused_cache[key] = fn
             if len(self._fused_cache) > self._fused_cache_max:
                 self._fused_cache.popitem(last=False)
@@ -909,7 +927,6 @@ class RoundEngine:
             m, n_out = a_shape[0], b_shape[-1]
 
             def _curve(results, w_lo, w_hi, valid, a, b):
-                self.trace_count += 1      # runs at trace time only
                 from ..kernels.ops import prefix_decode
                 e = w_lo.shape[0]
                 dec = prefix_decode(jnp.concatenate([w_lo, w_hi], axis=0),
@@ -931,7 +948,7 @@ class RoundEngine:
                        jnp.maximum(jnp.linalg.norm(ref), 1e-12))
                 return prod, prox, rel
 
-            fn = jax.jit(_curve)
+            fn = self._jit(_curve, "anytime_curve")
             self._fused_cache[key] = fn
             if len(self._fused_cache) > self._fused_cache_max:
                 self._fused_cache.popitem(last=False)
@@ -1217,13 +1234,14 @@ class RoundEngine:
         by the controller: retune (maybe) before, observe arrivals after
         — the round itself runs the unchanged engine paths.
         """
-        if self.adaptive is not None:
-            self._adaptive_retune(round_idx)
-            out, stats = self._matmul_inner(a, b, round_idx)
-            self._adaptive_observe(round_idx, stats,
-                                   (np.shape(a), np.shape(b)))
-            return out, stats
-        return self._matmul_inner(a, b, round_idx)
+        with span("round"):
+            if self.adaptive is not None:
+                self._adaptive_retune(round_idx)
+                out, stats = self._matmul_inner(a, b, round_idx)
+                self._adaptive_observe(round_idx, stats,
+                                       (np.shape(a), np.shape(b)))
+                return out, stats
+            return self._matmul_inner(a, b, round_idx)
 
     def _matmul_inner(self, a: np.ndarray, b: np.ndarray, round_idx: int = 0):
         a = jnp.asarray(a, jnp.float32)

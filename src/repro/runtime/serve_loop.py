@@ -41,6 +41,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from .spans import span
+
 __all__ = ["Request", "ServedRequest", "ServeResult", "poisson_workload",
            "ContinuousBatcher", "logit_gap"]
 
@@ -88,6 +90,9 @@ class ServeResult:
     trace_count: int                 # step-program traces (compile events)
     mode: str                        # "instep" | "round" | "plain"
     coded_fraction: float            # analytic coded share of step FLOPs
+    # (n_steps,) host perf_counter seconds from the pass's start to the
+    # moment each step's outputs were ready (its timed dispatch's end)
+    step_end_wall_s: np.ndarray
 
     @property
     def n_steps(self) -> int:
@@ -265,10 +270,11 @@ class ContinuousBatcher:
 
         def step(*args):
             self.trace_count += 1            # runs at trace time only
-            out, nc = self._forward(*args)
-            if self.mode == "round":
-                return out, nc
-            return jnp.argmax(out, axis=-1).astype(jnp.int32), out, nc
+            with span("trace", fn="serve_step"):
+                out, nc = self._forward(*args)
+                if self.mode == "round":
+                    return out, nc
+                return jnp.argmax(out, axis=-1).astype(jnp.int32), out, nc
 
         self._step = jax.jit(step)
         self._warm: set = set()              # buckets already compiled
@@ -277,27 +283,30 @@ class ContinuousBatcher:
     def _slice_cache(self, cache, b):
         """The leading-``b``-slots view the bucketed step runs on
         (prelude leaves batch on axis 0, group leaves on axis 1)."""
-        return {"prelude": self._jax.tree.map(lambda a: a[:b],
-                                              cache["prelude"]),
-                "groups": self._jax.tree.map(lambda a: a[:, :b],
-                                             cache["groups"])}
+        with span("serve.cache"):
+            return {"prelude": self._jax.tree.map(lambda a: a[:b],
+                                                  cache["prelude"]),
+                    "groups": self._jax.tree.map(lambda a: a[:, :b],
+                                                 cache["groups"])}
 
     def _merge_cache(self, cache, new, b):
-        return {"prelude": self._jax.tree.map(
-                    lambda full, nw: full.at[:b].set(nw),
-                    cache["prelude"], new["prelude"]),
-                "groups": self._jax.tree.map(
-                    lambda full, nw: full.at[:, :b].set(nw),
-                    cache["groups"], new["groups"])}
+        with span("serve.cache"):
+            return {"prelude": self._jax.tree.map(
+                        lambda full, nw: full.at[:b].set(nw),
+                        cache["prelude"], new["prelude"]),
+                    "groups": self._jax.tree.map(
+                        lambda full, nw: full.at[:, :b].set(nw),
+                        cache["groups"], new["groups"])}
 
     def _gather_cache(self, cache, perm):
         """Slot compaction after evictions: row ``i`` ← old row
         ``perm[i]``."""
-        idx = self._jnp.asarray(perm, self._jnp.int32)
-        return {"prelude": self._jax.tree.map(lambda a: a[idx],
-                                              cache["prelude"]),
-                "groups": self._jax.tree.map(lambda a: a[:, idx],
-                                             cache["groups"])}
+        with span("serve.cache"):
+            idx = self._jnp.asarray(perm, self._jnp.int32)
+            return {"prelude": self._jax.tree.map(lambda a: a[idx],
+                                                  cache["prelude"]),
+                    "groups": self._jax.tree.map(lambda a: a[:, idx],
+                                                 cache["groups"])}
 
     def _zero_slot(self, cache, i):
         """Admission reset.  KV reads are position-masked so stale keys
@@ -305,8 +314,9 @@ class ContinuousBatcher:
         admitted request must start from zeros."""
         z = lambda a: a.at[i].set(self._jnp.zeros_like(a[i]))
         zg = lambda a: a.at[:, i].set(self._jnp.zeros_like(a[:, i]))
-        return {"prelude": self._jax.tree.map(z, cache["prelude"]),
-                "groups": self._jax.tree.map(zg, cache["groups"])}
+        with span("serve.cache"):
+            return {"prelude": self._jax.tree.map(z, cache["prelude"]),
+                    "groups": self._jax.tree.map(zg, cache["groups"])}
 
     # ----------------------------------------------------------- stepping
     def _site_t_comp(self, b: int) -> float:
@@ -319,41 +329,51 @@ class ContinuousBatcher:
         return self._t_comp[b]
 
     def _timed(self, b, *args):
-        """Dispatch the step at bucket ``b``, returning (out, wall_s) with
-        compile excluded: the first call at a new bucket compiles and
-        runs, then an identical (pure) call is timed."""
+        """Dispatch the step at bucket ``b``, returning (out, wall_s,
+        end) with compile excluded: the first call at a new bucket
+        compiles and runs, then an identical (pure) call is timed; ``end``
+        is the host clock when its outputs were ready."""
         jax = self._jax
         if b not in self._warm:
-            out = self._step(*args)
-            jax.block_until_ready(out)
+            with span("serve.dispatch", new_bucket=1):
+                out = self._step(*args)
+            with span("serve.wait"):
+                jax.block_until_ready(out)
             self._warm.add(b)
         t0 = time.perf_counter()
-        out = self._step(*args)
-        jax.block_until_ready(out)
-        return out, time.perf_counter() - t0
+        with span("serve.dispatch", new_bucket=0):
+            out = self._step(*args)
+        with span("serve.wait"):
+            jax.block_until_ready(out)
+        end = time.perf_counter()
+        return out, end - t0, end
 
     def _run_step(self, cache, tok, pos, b):
         """One step at bucket ``b``: returns (next_tokens (b,), logits
         (b, V) — on the device, except in round mode — new cache,
-        RoundStats, virtual_dur_s, wall_s)."""
+        RoundStats, virtual_dur_s, wall_s, end): ``end`` is the host clock
+        when the step's outputs were ready."""
         jnp = self._jnp
         from .engine import RoundStats
         sliced = self._slice_cache(cache, b)
-        tok_a = jnp.asarray(tok[:b, None], jnp.int32)
-        pos_a = jnp.asarray(pos[:b], jnp.int32)
+        plan, mats, crypto = None, {}, 0.0
         if self.mode == "instep":
-            plan = self.engine.serve_round_plan(self._round,
-                                               self._site_t_comp(b))
-            self._round += 1
-            crypto = 0.0
-            mats = {}
-            if self.wire_params is not None:
-                mats = self.code.step_materials(self.engine)
-                crypto = self.engine.serve_crypto_time(
-                    *self.code.wire_elems(b))
-            (nxt, logits, new_cache), wall = self._timed(
-                b, self.params, sliced, tok_a, pos_a,
-                jnp.asarray(plan.mask), self.code.arrays, mats)
+            with span("serve.plan"):
+                plan = self.engine.serve_round_plan(self._round,
+                                                   self._site_t_comp(b))
+                self._round += 1
+                if self.wire_params is not None:
+                    mats = self.code.step_materials(self.engine)
+                    crypto = self.engine.serve_crypto_time(
+                        *self.code.wire_elems(b))
+        with span("serve.inputs"):
+            tok_a = jnp.asarray(tok[:b, None], jnp.int32)
+            pos_a = jnp.asarray(pos[:b], jnp.int32)
+            mask = None if plan is None else jnp.asarray(plan.mask)
+        if self.mode == "instep":
+            (nxt, logits, new_cache), wall, end = self._timed(
+                b, self.params, sliced, tok_a, pos_a, mask,
+                self.code.arrays, mats)
             self.engine.dispatch_count += 1
             stats = self.engine._stats(
                 plan.events, plan.wait_s, encode_s=wall,
@@ -361,24 +381,29 @@ class ContinuousBatcher:
                 n_waited=len(plan.responders), dispatches=1)
             virt = stats.total_s
         elif self.mode == "round":
-            (h, new_cache), wall = self._timed(b, self.params, sliced,
-                                               tok_a, pos_a)
+            (h, new_cache), wall, _ = self._timed(b, self.params, sliced,
+                                                  tok_a, pos_a)
             t0 = time.perf_counter()
-            prod, stats = self.engine.matmul(self._wt, np.asarray(h).T,
+            with span("serve.to_host"):
+                h = np.asarray(h)
+            prod, stats = self.engine.matmul(self._wt, h.T,
                                              round_idx=self._round)
-            wall += time.perf_counter() - t0
+            end = time.perf_counter()
+            wall += end - t0
             self._round += 1
             logits = np.asarray(prod).T
             nxt = logits.argmax(-1).astype(np.int32)
             virt = stats.total_s
         else:
-            (nxt, logits, new_cache), wall = self._timed(
+            (nxt, logits, new_cache), wall, end = self._timed(
                 b, self.params, sliced, tok_a, pos_a)
             stats = RoundStats(encode_s=wall, compute_wait_s=0.0,
                                decode_s=0.0, policy="uncoded", dispatches=1)
             virt = wall
         cache = self._merge_cache(cache, new_cache, b)
-        return np.asarray(nxt), logits, cache, stats, virt, wall
+        with span("serve.to_host"):
+            nxt = np.asarray(nxt)
+        return nxt, logits, cache, stats, virt, wall, end
 
     # --------------------------------------------------------------- loop
     def run(self, requests: Sequence[Request], *,
@@ -387,13 +412,14 @@ class ContinuousBatcher:
         each step's logits to the host and returns every request's rows
         in :attr:`ServedRequest.logits` — what parity checks compare,
         since greedy tokens flip on near-ties."""
+        t_pass = time.perf_counter()
         reqs = sorted(requests, key=lambda r: (r.arrival_s, r.rid))
         max_len = max(len(r.prompt) + r.gen for r in reqs) + 1
         cache = self.model.init_cache(self.max_slots, max_len)
         pending = deque(reqs)
         slots: List[_Slot] = []
         served: List[ServedRequest] = []
-        step_stats, virt_log, bucket_log = [], [], []
+        step_stats, virt_log, bucket_log, end_log = [], [], [], []
         t_v = 0.0
         busy = 0.0
         tok = np.zeros(self.max_slots, np.int32)
@@ -415,70 +441,82 @@ class ContinuousBatcher:
                             n_prompt=len(r.prompt),
                             tokens=np.zeros(0, np.int32)))
                         continue
-                    cache = self._zero_slot(cache, len(slots))
-                    slots.append(_Slot(req=r, admitted_s=t_v))
+                    with span("serve.admit"):
+                        cache = self._zero_slot(cache, len(slots))
+                        slots.append(_Slot(req=r, admitted_s=t_v))
             if not slots:
                 if not pending:                  # everything drained
                     break
                 t_v = max(t_v, pending[0].arrival_s)   # idle: jump ahead
                 continue
 
-            # ---- assemble the bucketed step
+            # ---- the bucketed step: ``live`` of its ``b`` rows serve a
+            # request (a gated batch's finished ones hold theirs idle)
             b = _next_pow2(len(slots))
-            for i, s in enumerate(slots):
-                plen = len(s.req.prompt)
-                tok[i] = s.req.prompt[s.fed] if s.fed < plen else s.last_tok
-                pos[i] = s.fed
-            tok[len(slots):b] = 0                # padded slots: ignored rows
-            pos[len(slots):b] = 0
-            nxt, logits, cache, stats, virt, wall = self._run_step(
-                cache, tok, pos, b)
-            if record_logits:
-                logits = np.asarray(logits, np.float32)
+            live = sum(not s.done for s in slots)
+            with span("serve.step", bucket=b, live=live):
                 for i, s in enumerate(slots):
-                    if not s.done:
-                        s.logits.append(logits[i])
-            busy += wall
-            t_v += virt
-            step_stats.append(stats)
-            virt_log.append(virt)
-            bucket_log.append(b)
+                    plen = len(s.req.prompt)
+                    tok[i] = (s.req.prompt[s.fed] if s.fed < plen
+                              else s.last_tok)
+                    pos[i] = s.fed
+                tok[len(slots):b] = 0            # padded slots: ignored rows
+                pos[len(slots):b] = 0
+                nxt, logits, cache, stats, virt, wall, end = self._run_step(
+                    cache, tok, pos, b)
+                if record_logits:
+                    with span("serve.to_host"):
+                        logits = np.asarray(logits, np.float32)
+                    for i, s in enumerate(slots):
+                        if not s.done:
+                            s.logits.append(logits[i])
+                busy += wall
+                t_v += virt
+                step_stats.append(stats)
+                virt_log.append(virt)
+                bucket_log.append(b)
+                end_log.append(end - t_pass)
 
-            # ---- consume outputs, evict finishers
-            finished: List[int] = []
-            for i, s in enumerate(slots):
-                if s.done:
-                    continue
-                plen = len(s.req.prompt)
-                if s.fed >= plen - 1:            # argmax is a generated token
-                    t = int(nxt[i])
-                    s.tokens.append(t)
-                    s.last_tok = t
-                    if len(s.tokens) == 1:
-                        s.first_token_s = t_v
-                    if (len(s.tokens) >= s.req.gen
-                            or (self.eos_id is not None and t == self.eos_id)):
-                        s.done = True
-                        served.append(ServedRequest(
-                            rid=s.req.rid, arrival_s=s.req.arrival_s,
-                            admitted_s=s.admitted_s,
-                            first_token_s=s.first_token_s, done_s=t_v,
-                            n_prompt=plen,
-                            tokens=np.asarray(s.tokens, np.int32),
-                            logits=(np.stack(s.logits) if record_logits
-                                    else None)))
-                        finished.append(i)
-                s.fed += 1
-            if self.admission == "gated":
-                # finished requests hold their slots until the batch drains
-                if all(s.done for s in slots):
-                    slots = []
-            elif finished:
-                keep = [i for i in range(len(slots)) if i not in finished]
-                perm = keep + [i for i in range(self.max_slots)
-                               if i not in keep]
-                cache = self._gather_cache(cache, perm[:self.max_slots])
-                slots = [slots[i] for i in keep]
+                # ---- consume outputs, evict finishers
+                with span("serve.consume"):
+                    finished: List[int] = []
+                    for i, s in enumerate(slots):
+                        if s.done:
+                            continue
+                        plen = len(s.req.prompt)
+                        if s.fed >= plen - 1:    # argmax is a generated token
+                            t = int(nxt[i])
+                            s.tokens.append(t)
+                            s.last_tok = t
+                            if len(s.tokens) == 1:
+                                s.first_token_s = t_v
+                            if (len(s.tokens) >= s.req.gen
+                                    or (self.eos_id is not None
+                                        and t == self.eos_id)):
+                                s.done = True
+                                served.append(ServedRequest(
+                                    rid=s.req.rid, arrival_s=s.req.arrival_s,
+                                    admitted_s=s.admitted_s,
+                                    first_token_s=s.first_token_s, done_s=t_v,
+                                    n_prompt=plen,
+                                    tokens=np.asarray(s.tokens, np.int32),
+                                    logits=(np.stack(s.logits)
+                                            if record_logits else None)))
+                                finished.append(i)
+                        s.fed += 1
+                    if self.admission == "gated":
+                        # finished requests hold their slots until the
+                        # batch drains
+                        if all(s.done for s in slots):
+                            slots = []
+                    elif finished:
+                        keep = [i for i in range(len(slots))
+                                if i not in finished]
+                        perm = keep + [i for i in range(self.max_slots)
+                                       if i not in keep]
+                        cache = self._gather_cache(cache,
+                                                   perm[:self.max_slots])
+                        slots = [slots[i] for i in keep]
 
         served.sort(key=lambda r: r.rid)
         return ServeResult(
@@ -486,7 +524,8 @@ class ContinuousBatcher:
             step_virtual_s=np.asarray(virt_log),
             buckets=np.asarray(bucket_log, np.int64), busy_wall_s=busy,
             virtual_s=t_v, trace_count=self.trace_count, mode=self.mode,
-            coded_fraction=self.coded_fraction)
+            coded_fraction=self.coded_fraction,
+            step_end_wall_s=np.asarray(end_log))
 
 
 def logit_gap(served: Sequence[ServedRequest],
