@@ -17,7 +17,7 @@ SPANS = {
     "round.plan": "worker pricing, straggler draw and plan_round",
     "round.dispatch": "enqueue of the round program",
     "round.wait": "block_until_ready on the round's product",
-    "round.to_host": "copy of the product to the host",
+    "round.to_host": "copy of the product to the host (chunked, bytes)",
     "serve.admit": "one admission into a slot",
     "serve.step": "one loop step (bucket: padded rows, live: used rows)",
     "serve.inputs": "token, position and mask arrays of a step",

@@ -30,6 +30,7 @@ from repro.kernels import ops
 from repro.kernels.berrut_encode import berrut_encode_kernel
 from repro.kernels.coded_matmul import coded_matmul_kernel
 from repro.kernels.mask_add import mask_add_kernel
+from repro.runtime import engine
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 import chip_smoke  # noqa: E402
@@ -134,6 +135,17 @@ def test_fused_round_compiles(one_chip, on_tpu):
         fn = s.engine._fused_fn((ROWS, D), (D, N_OUT), "float32")
         args = shaped(one_chip, (ROWS, D), (D, N_OUT), (N,))
         check_compiled(fn.lower(*args).compile())
+
+
+def test_fused_round_in_row_chunks_compiles(one_chip, on_tpu, monkeypatch):
+    # the 37.7 MB product leaves the program as the TPU's eight row chunks
+    monkeypatch.setattr(engine, "_CHUNK_PLATFORMS", (jax.default_backend(),))
+    with fig3_engine() as s:
+        fn = s.engine._fused_fn((ROWS, D), (D, N_OUT), "float32")
+        args = shaped(one_chip, (ROWS, D), (D, N_OUT), (N,))
+        compiled = fn.lower(*args).compile()
+    check_compiled(compiled)
+    assert [o.shape for o in compiled.out_info] == [(ROWS // 8, N_OUT)] * 8
 
 
 @pytest.mark.parametrize("mode", ["paper", "stream"])

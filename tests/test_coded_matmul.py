@@ -6,16 +6,21 @@ straggler-mask sweeps; the no-full-payload-padding regression for the
 upgraded kernels; and the recompile-count contract of the jitted round
 path (shape change recompiles, mask change never does)."""
 
+import threading
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.api import (ClusterSpec, CodeSpec, CryptoSpec, PrivacySpec,
+                       Session, StragglerSpec)
 from repro.core import registry
 from repro.kernels import ref
 from repro.kernels.berrut_encode import berrut_encode_kernel
 from repro.kernels.coded_matmul import coded_matmul_kernel
 from repro.kernels.ops import coded_matmul
+from repro.runtime import engine
 from repro.runtime.master_worker import DistributedMatmul
 
 rng = np.random.default_rng(0)
@@ -265,3 +270,67 @@ def test_spacdc_decode_matrix_cached_by_responder_tuple():
     assert m1 is m2                      # same object: cache hit
     info = code._decode_matrix_cached.cache_info()
     assert info.hits >= 1 and info.misses == 1
+
+
+# --------------------------------------------------------------------------
+# the product on the host: what Session.matmul hands back from the program
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("m,n,encrypt,chunked", [
+    (30, 24, None, False),    # m not a multiple of K = 4: decode pads rows
+    (32, 130, None, False),   # n not a multiple of 128
+    (5, 7, None, False),      # a product of under 128 elements
+    (100, 24, None, True),    # seven row chunks, the last of four rows
+    (30, 24, "real", False),  # the encrypted one-dispatch round, stream mode
+    (100, 24, "real", True),
+])
+def test_session_product_is_the_round_chain_bit_for_bit(m, n, encrypt,
+                                                        chunked, monkeypatch):
+    """The fused round's product reaches the caller as the (m, n) float32
+    C-contiguous array that the round's own chain (masked decode, then
+    reassembly) computes, bit for bit, whatever layout it left the
+    program in (whole, or in row chunks that host threads copy, which
+    ``chunked`` turns on here at any size, as on a TPU from 32 MB on)."""
+    if chunked:
+        monkeypatch.setattr(engine, "_CHUNK_PLATFORMS",
+                            (jax.default_backend(),))
+        monkeypatch.setattr(engine, "_CHUNK_MIN_BYTES", 0)
+    spec = ClusterSpec(
+        code=CodeSpec(scheme="spacdc", n_workers=10, k_blocks=4),
+        privacy=PrivacySpec(t_colluding=1, noise_scale=0.05),
+        straggler=StragglerSpec(n_stragglers=2),
+        crypto=CryptoSpec(encrypt=encrypt, cipher_mode="stream"), seed=3)
+    r = np.random.default_rng(m * n)
+    a = jnp.asarray(r.standard_normal((m, 16)), jnp.float32)
+    b = jnp.asarray(r.standard_normal((16, n)), jnp.float32)
+    with Session(spec) as s:
+        out, stats = s.matmul(a, b, round_idx=1)
+        scheme = s.engine.scheme
+        assert (s.engine._copy_pool is not None) == chunked
+    assert stats.dispatches == 1                  # a one-dispatch round
+    # closing the session ends the threads that copied row chunks
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("round-copy")]
+    mask = np.zeros(10, np.float32)
+    mask[[w for _, w in stats.arrivals[:stats.n_waited]]] = 1.0
+    chain = jax.jit(lambda a, b, mask: scheme.reconstruct_matmul(
+        scheme.fused_round(a, b, mask), m, n))
+    want = np.asarray(chain(a, b, jnp.asarray(mask)))
+    assert out.shape == (m, n) and out.dtype == np.float32
+    assert out.flags.c_contiguous and not out.flags.writeable
+    np.testing.assert_array_equal(out, want)
+
+
+@pytest.mark.parametrize("platform,m,n,chunks", [
+    ("tpu", 18432, 512, [2304] * 8),          # fig. 3's 37.7 MB product
+    ("tpu", 16392, 512, [2056] * 7 + [2000]), # 32 MB and up: chunks
+    ("tpu", 16376, 512, [16376]),             # just under 32 MB: whole
+    ("tpu", 8, 1 << 20, [8]),                 # one tile row: nothing to cut
+    ("tpu", 256, 64, [256]),
+    ("cpu", 18432, 512, [18432]),           # np.asarray is cheap there
+])
+def test_row_cuts_split_large_tpu_products_into_whole_tiles(platform, m, n,
+                                                          chunks):
+    cuts = engine._row_cuts(m, n, 4, platform)
+    assert all(c % 8 == 0 for c in cuts)
+    assert np.diff([0, *cuts, m]).tolist() == chunks

@@ -20,7 +20,7 @@ from repro.runtime.spans import SPANS
 
 SRC = Path(__file__).resolve().parents[1] / "src" / "repro"
 # the counters a span may carry, each read by a benchmark metric or a test
-COUNTERS = {"bucket", "live", "new_bucket", "fn"}
+COUNTERS = {"bucket", "live", "new_bucket", "fn", "chunked", "bytes"}
 
 SPEC = ClusterSpec(
     code=CodeSpec(scheme="spacdc", n_workers=10, k_blocks=4),
@@ -115,6 +115,16 @@ def test_fused_round_holds_one_of_each_inner_span(rounds):
     for outer in top:
         for name in ("plan", "dispatch", "wait", "to_host"):
             assert len(_inside(events, outer, f"spacdc.round.{name}")) == 1
+
+
+def test_copy_to_host_carries_the_product_bytes_and_its_layout(rounds):
+    _, events, _ = rounds
+    copies = [ev[4] for ev in events if ev[3] == "spacdc.round.to_host"]
+    # the rounds' products, 48 x 8 twice, then 96 x 8, each leaving the
+    # round program whole (row chunks only from 32 MB on, and on a TPU)
+    want = [{"bytes": 4 * m * n, "chunked": 0}
+            for m, n in ((48, 8), (48, 8), (96, 8))]
+    assert copies == want
 
 
 def test_trace_span_marks_only_the_rounds_that_compiled(rounds):
