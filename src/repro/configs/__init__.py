@@ -3,13 +3,14 @@
 from .base import SHAPES, ModelConfig, ShapeSpec
 
 from . import (command_r_35b, deepseek_v2_lite, jamba_v01_52b, llama4_scout,
-               phi3_mini, qwen2_7b, qwen2_vl_72b, qwen3_14b, rwkv6_1b6,
-               whisper_small)
+               moonlight_16b_a3b, phi3_mini, qwen2_7b, qwen2_vl_72b,
+               qwen3_14b, rwkv6_1b6, whisper_small)
 
 ARCHS = {
     "whisper-small": whisper_small.CONFIG,
     "rwkv6-1.6b": rwkv6_1b6.CONFIG,
     "deepseek-v2-lite-16b": deepseek_v2_lite.CONFIG,
+    "moonlight-16b-a3b": moonlight_16b_a3b.CONFIG,
     "llama4-scout-17b-a16e": llama4_scout.CONFIG,
     "phi3-mini-3.8b": phi3_mini.CONFIG,
     "qwen2-7b": qwen2_7b.CONFIG,
@@ -58,8 +59,14 @@ def tiny_config(name: str) -> ModelConfig:
         reduced.update(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
                        v_head_dim=16, head_dim=24)
     if cfg.moe:
-        reduced.update(n_experts=8 if cfg.n_experts >= 64 else 4,
-                       top_k=min(cfg.top_k, 2), moe_d_ff=128)
+        # the router fields carry over as published; a held share keeps
+        # its place among the experts, scaled to the tiny count
+        n = 8 if cfg.n_experts >= 64 else 4
+        first, count = cfg.experts_held
+        reduced.update(n_experts=n, top_k=min(cfg.top_k, 2), moe_d_ff=128,
+                       held_experts=(() if not cfg.held_experts else
+                                     (first * n // cfg.n_experts,
+                                      max(1, count * n // cfg.n_experts))))
     if cfg.ssm_type == "rwkv6":
         reduced.update(rwkv_head_dim=16, n_heads=4, n_kv_heads=4)
     if cfg.ssm_type == "mamba":

@@ -55,6 +55,12 @@ class ModelConfig:
     moe_layer_offset: int = 0     # ... starting at this offset
     first_dense_layers: int = 0   # deepseek: first k layers use dense FFN
     capacity_factor: float = 1.25
+    router_scoring: str = "softmax"   # softmax | sigmoid (DeepSeek-V3: the
+                                  # top-k picks by score + a selection bias)
+    norm_topk_prob: bool = True   # rescale the k chosen weights to sum to 1
+    routed_scaling_factor: float = 1.0   # then multiply them by this
+    held_experts: Tuple[int, ...] = ()   # (first, count) of the routed
+                                  # experts this chip holds; () = all
 
     # --- SSM / hybrid ---
     ssm_type: str = ""            # "rwkv6" | "mamba" | ""
@@ -110,6 +116,11 @@ class ModelConfig:
         if self.n_kv_heads == self.n_heads:
             return self.n_heads_padded
         return self.n_kv_heads
+
+    @property
+    def experts_held(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts an MoE layer holds here."""
+        return tuple(self.held_experts) or (0, self.n_experts)
 
     def is_moe_layer(self, idx: int) -> bool:
         if not self.moe:

@@ -2,7 +2,9 @@
 
 27L, d_model=2048, 16H, MLA kv_lora_rank=512 (no q-lora in Lite),
 qk_nope=128 / qk_rope=64 / v_head=128.  MoE: 64 routed experts top-6 +
-2 shared, expert d_ff=1408; first layer dense with d_ff=10944.
+2 shared, expert d_ff=1408; first layer dense with d_ff=10944.  Router as
+published: softmax scores, the top-6 weights neither renormalised
+(``norm_topk_prob`` false) nor scaled (``routed_scaling_factor`` 1).
 (The pool line's "160 routed" is full V2; Lite per hf config has 64 routed,
 matching the pool's own "MoE 64e top-6" bracket — documented in DESIGN.md.)
 """
@@ -30,5 +32,8 @@ CONFIG = ModelConfig(
     top_k=6,
     moe_d_ff=1408,
     first_dense_layers=1,
+    router_scoring="softmax",
+    norm_topk_prob=False,
+    routed_scaling_factor=1.0,
     rope_theta=10_000.0,
 )

@@ -18,6 +18,7 @@ from . import ref
 from .berrut_encode import berrut_encode_kernel
 from .coded_matmul import coded_matmul_kernel
 from .flash_attention import flash_attention_kernel
+from .held_experts import held_experts_kernel
 from .mask_add import mask_add_kernel
 
 
@@ -82,6 +83,21 @@ def coded_matmul(weights, blocks, rhs, *, force_kernel: bool | None = None):
         return coded_matmul_kernel(weights, blocks, rhs,
                                    interpret=not _on_tpu())
     return ref.coded_matmul(weights, blocks, rhs)
+
+
+def held_experts(x, gates, w_gate, w_up, w_down):
+    """One decode step of the routed experts a chip holds, with kernel
+    dispatch: the Pallas kernel on a TPU, its XLA twin elsewhere.
+
+    x (B, d) in the compute dtype; gates (B, E) routed weights (zero
+    where a row did not choose the expert); w_gate, w_up (E, d, f);
+    w_down (E, f, d).  Returns (B, d) float32:
+    ``sum_e gates[:, e] * (silu(x W_g,e) * x W_u,e) W_d,e``.
+    """
+    if _on_tpu():
+        return held_experts_kernel(x, gates, w_gate, w_up, w_down,
+                                   interpret=False)
+    return ref.held_experts(x, gates, w_gate, w_up, w_down)
 
 
 @functools.partial(jax.jit, static_argnames=("q", "use_kernel", "interpret",

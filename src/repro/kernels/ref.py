@@ -35,6 +35,25 @@ def coded_matmul(weights: jnp.ndarray, blocks: jnp.ndarray,
     return out.astype(blocks.dtype)
 
 
+def held_experts(x, gates, w_gate, w_up, w_down) -> jnp.ndarray:
+    """Held-experts decode oracle, the kernel's math unfused: every held
+    expert on every row, in the compute dtype (x's) with float32
+    accumulation, each row's hidden state scaled by its gate before the
+    down projection.
+
+    x (B, d); gates (B, E); w_gate, w_up (E, d, f); w_down (E, f, d)
+    -> (B, d) float32.
+    """
+    cd, f32 = x.dtype, jnp.float32
+    gate = jnp.einsum("bd,edf->ebf", x, w_gate.astype(cd),
+                      preferred_element_type=f32)
+    up = jnp.einsum("bd,edf->ebf", x, w_up.astype(cd),
+                    preferred_element_type=f32)
+    h = (jax.nn.silu(gate) * up * gates.astype(f32).T[..., None]).astype(cd)
+    return jnp.einsum("ebf,efd->bd", h, w_down.astype(cd),
+                      preferred_element_type=f32)
+
+
 def mask_add(payload: jnp.ndarray, mask: jnp.ndarray, q_limbs,
              *, subtract: bool = False) -> jnp.ndarray:
     """MEA-ECC mask add/sub oracle: (payload ± mask) mod q over uint32 limb

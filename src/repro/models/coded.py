@@ -12,7 +12,14 @@ projection the :class:`~repro.api.spec.ServeSpec` selects:
 * ``o``   — the output projection (``wo`` flattened to 2-D);
 * ``up``  — FFN up (gate|up stacked for swiglu);
 * ``down``— FFN down;
+* ``shared_up`` / ``shared_down`` — an MoE layer's shared experts, one
+  SwiGLU block (gate|up stacked, then down);
 * the unembed (always coded unless ``coded_layers="none"``).
+
+What stays uncoded, on the master: an MoE layer's router and its routed
+experts — routing is data dependent, so no fixed ``x @ W`` exists to
+pre-encode; the held experts run in ``kernels.held_experts`` — MLA's
+latent ``w_uk`` / ``w_uv`` contractions (per-head maps), and SSM mixers.
 
 Weights are encoded **once** at serve start (they are what lives on the
 workers); only activations move per step.  All sites of a step share ONE
@@ -46,7 +53,7 @@ __all__ = ["SiteMeta", "ServingCode", "layer_sites", "encode_serving_weights",
            "build_coded_logits", "coded_flop_fraction"]
 
 # deterministic site iteration order (material assignment, t_comp sums)
-SITE_ORDER = ("qkv", "o", "up", "down")
+SITE_ORDER = ("qkv", "o", "up", "down", "shared_up", "shared_down")
 
 # the workers' shard matmuls run at full f32 precision: the shards are
 # f32, and on TPU a default-precision f32 matmul rounds its operands to
@@ -71,10 +78,11 @@ def _ordered(metas: Dict[str, SiteMeta]):
 def layer_sites(cfg: ModelConfig, desc, coded_layers: str) -> Dict[str, SiteMeta]:
     """The coded sites of one layer under a ``coded_layers`` setting.
 
-    MoE and SSM (mamba/rwkv) mixers have no fixed ``x @ W`` to pre-encode
-    (data-dependent routing / recurrence) and stay uncoded — they only
-    show up in the FLOP-fraction denominator.  MLA's latent w_uk/w_uv
-    contractions are per-head maps, also kept on the master.
+    Routed experts and SSM (mamba/rwkv) mixers have no fixed ``x @ W`` to
+    pre-encode (data-dependent routing / recurrence) and stay uncoded —
+    they only show up in the FLOP-fraction denominator; an MoE layer's
+    shared experts are coded.  MLA's latent w_uk/w_uv contractions are
+    per-head maps, also kept on the master.
     """
     sites: Dict[str, SiteMeta] = {}
     want_attn = coded_layers in ("attn", "all")
@@ -98,6 +106,10 @@ def layer_sites(cfg: ModelConfig, desc, coded_layers: str) -> Dict[str, SiteMeta
         else:
             sites["up"] = SiteMeta("up", d, ff, (ff,))
         sites["down"] = SiteMeta("down", ff, d, (d,))
+    if want_ffn and desc.ffn == "moe" and cfg.n_shared_experts:
+        sf = cfg.n_shared_experts * cfg.moe_d_ff
+        sites["shared_up"] = SiteMeta("shared_up", d, 2 * sf, (sf, sf))
+        sites["shared_down"] = SiteMeta("shared_down", sf, d, (d,))
     return sites
 
 
@@ -119,8 +131,13 @@ def _site_weight(lp, name: str, cfg: ModelConfig, desc):
         f = lp["ffn"]
         w = (jnp.concatenate([f["w_gate"], f["w_up"]], axis=1)
              if cfg.activation == "swiglu" else f["w_up"])
-    else:                                                 # down
+    elif name == "down":
         w = lp["ffn"]["w_down"]
+    elif name == "shared_up":
+        f = lp["ffn"]["shared"]
+        w = jnp.concatenate([f["w_gate"], f["w_up"]], axis=1)
+    else:                                                 # shared_down
+        w = lp["ffn"]["shared"]["w_down"]
     return w.astype(cd)
 
 
@@ -351,6 +368,16 @@ def _layer_proj(cfg: ModelConfig, desc, metas, arrays, dec_w, *, wire=None,
             b = h.shape[0]
             return run("down", h.reshape(b, -1)).astype(cd)[:, None, :]
         proj["down"] = down_fn
+    if "shared_up" in metas:
+        def shared_up_fn(x2):                             # (B,d) -> (gate, up)
+            y = run("shared_up", x2).astype(cd)
+            sf = metas["shared_up"].split[0]
+            return y[:, :sf], y[:, sf:]
+
+        def shared_down_fn(h):                            # (B,sf) -> (B,d)
+            return run("shared_down", h).astype(cd)
+        proj["shared_up"] = shared_up_fn
+        proj["shared_down"] = shared_down_fn
     return proj
 
 
@@ -362,7 +389,9 @@ def build_coded_logits(model, scheme, code: ServingCode, *,
     batch bucket; the logits themselves are what parity checks compare.
 
     Returns ``logits_step(params, cache, tokens (B,1), pos (B,), mask
-    (N,), weights, materials) -> (logits (B, V) f32, new_cache)``.
+    (N,), weights, materials) -> (logits (B, V) f32, new_cache, here)``:
+    ``here`` is None for a model without MoE layers, else (B,) int32, each
+    row's routing choices that fell on held experts, summed over layers.
     ``mask``, ``pos`` and ``materials`` are runtime arguments — responder
     churn, slot churn inside a bucket and fresh nonces never retrace.
     """
@@ -386,38 +415,43 @@ def build_coded_logits(model, scheme, code: ServingCode, *,
     def logits_step(params, cache, tokens, pos, mask, weights, materials):
         dec_w = scheme.decode_matrix_masked(mask)          # (K, N)
         x = embed(params["embedding"], tokens, cfg)
-        new_pre = []
+        new_pre, here = [], None
         for i, lp in enumerate(params["prelude"]):
             desc = layer_desc(cfg, i)
             proj = _layer_proj(
                 cfg, desc, code.prelude_meta[i], weights["prelude"][i], dec_w,
                 wire=wire, mats=materials["prelude"][i] if use_wire else None,
                 force_kernel=force_kernel)
-            x, nc = decode_layer(lp, x, cfg, desc, cache=cache["prelude"][i],
-                                 pos=pos, proj=proj)
+            x, nc, h = decode_layer(lp, x, cfg, desc,
+                                    cache=cache["prelude"][i], pos=pos,
+                                    proj=proj)
             new_pre.append(nc)
+            here = _plus(here, h)
 
         def group_body(x, xs):
             if use_wire:
                 gp, gc, gw, gm = xs
             else:
                 (gp, gc, gw), gm = xs, {}
-            new_gc = {}
+            new_gc, group_here = {}, None
             for i in range(model.period):
                 desc = model.descs[i]
                 proj = _layer_proj(cfg, desc, code.group_meta[f"pos{i}"],
                                    gw[f"pos{i}"], dec_w, wire=wire,
                                    mats=gm.get(f"pos{i}") if use_wire else None,
                                    force_kernel=force_kernel)
-                x, new_gc[f"pos{i}"] = decode_layer(
+                x, new_gc[f"pos{i}"], h = decode_layer(
                     gp[f"pos{i}"], x, cfg, desc, cache=gc[f"pos{i}"],
                     pos=pos, proj=proj)
-            return x, new_gc
+                group_here = _plus(group_here, h)
+            return x, (new_gc, group_here)
 
         xs = (params["groups"], cache["groups"], weights["group"])
         if use_wire:
             xs = xs + (materials["group"],)
-        x, new_groups = jax.lax.scan(group_body, x, xs)
+        x, (new_groups, groups_here) = jax.lax.scan(group_body, x, xs)
+        if groups_here is not None:
+            here = _plus(here, jnp.sum(groups_here, axis=0, dtype=jnp.int32))
         x = apply_norm(params["final_norm"], x, cfg)
         if code.unembed_meta is not None:
             logits = _coded_apply(weights["unembed"], x[:, 0, :], dec_w,
@@ -430,9 +464,14 @@ def build_coded_logits(model, scheme, code: ServingCode, *,
         else:
             logits = unembed(params["embedding"], x, cfg)[:, 0, :]
         return (logits.astype(jnp.float32),
-                {"prelude": new_pre, "groups": new_groups})
+                {"prelude": new_pre, "groups": new_groups}, here)
 
     return logits_step
+
+
+def _plus(a, b):
+    """a + b where either may be None (a layer that routes nothing)."""
+    return b if a is None else a if b is None else a + b
 
 
 
@@ -446,9 +485,12 @@ def coded_flop_fraction(cfg: ModelConfig, coded_layers: str = "all",
     model config (the acceptance gate's "reported from the model config").
 
     Counts every per-token matmul: projections, attention score/value
-    contractions at ``ctx_len`` cached tokens, FFN, unembed.  MoE and SSM
-    mixers are uncoded (coarse FLOP estimates — they only widen the
-    denominator); the common factor 2 (multiply-add) cancels.
+    contractions at ``ctx_len`` cached tokens, FFN, unembed.  An MoE
+    layer's shared experts are coded; its router and routed experts are
+    not — the routed work is ``top_k`` experts' times the held share of
+    all experts, what this chip computes on average.  SSM mixers are
+    uncoded (coarse FLOP estimates — they only widen the denominator); the
+    common factor 2 (multiply-add) cancels.
     """
     if coded_layers == "none":
         return 0.0
@@ -485,8 +527,13 @@ def coded_flop_fraction(cfg: ModelConfig, coded_layers: str = "all",
             if want_ffn:
                 coded += f
         elif desc.ffn == "moe":
-            experts = cfg.top_k + (cfg.n_shared_experts or 0)
-            total += (experts * 3 * d * cfg.moe_d_ff + d * cfg.n_experts)
+            expert = 3 * d * cfg.moe_d_ff
+            held = cfg.experts_held[1] / cfg.n_experts
+            shared = cfg.n_shared_experts * expert
+            total += (cfg.top_k * held * expert + shared
+                      + d * cfg.n_experts)
+            if want_ffn:
+                coded += shared
     unemb = d * cfg.vocab_size
     total += unemb
     coded += unemb

@@ -155,13 +155,16 @@ def apply_layer(p, x, cfg: ModelConfig, desc: LayerDesc, *, positions,
 
 def decode_layer(p, x, cfg: ModelConfig, desc: LayerDesc, *, cache, pos,
                  mrope_positions=None, proj=None):
-    """One-token layer step.  Returns (x, new_cache).
+    """One-token layer step.  Returns (x, new_cache, here): ``here`` is
+    None, or in an MoE layer (B,) int32, each row's routing choices that
+    fell on the experts this layer holds.
 
     ``pos`` is scalar (uniform) or (B,) per-slot positions (continuous
     batching); ``proj`` optionally reroutes this layer's projection matmuls
     through coded rounds: ``{"qkv", "o"}`` feed the attention/MLA mixer,
-    ``{"up", "down"}`` the dense FFN (MoE/SSM mixers stay uncoded — their
-    maps are data-dependent or recurrent, not a fixed ``x @ W``)."""
+    ``{"up", "down"}`` the dense FFN, ``{"shared_up", "shared_down"}`` an
+    MoE layer's shared experts (routed experts and SSM mixers stay uncoded
+    — their maps are data-dependent or recurrent, not a fixed ``x @ W``)."""
     proj = proj or {}
     h = apply_norm(p["norm1"], x, cfg)
     if desc.mixer == "rwkv":
@@ -169,7 +172,7 @@ def decode_layer(p, x, cfg: ModelConfig, desc: LayerDesc, *, cache, pos,
         x = x + y
         h2 = apply_norm(p["norm2"], x, cfg)
         y2, cache = ssm.rwkv_channel_mix_decode(p["mixer"], h2, cache, cfg)
-        return x + y2, cache
+        return x + y2, cache, None
     if desc.mixer == "mamba":
         y, cache = ssm.mamba_decode_step(p["mixer"], h, cache, cfg)
     elif desc.mixer == "mla":
@@ -183,14 +186,17 @@ def decode_layer(p, x, cfg: ModelConfig, desc: LayerDesc, *, cache, pos,
     ffn_mm = {"matmul_up": proj.get("up"), "matmul_down": proj.get("down")}
     if cfg.parallel_block:
         f = apply_ffn(p["ffn"], h, cfg, **ffn_mm)
-        return x + y + f, cache
+        return x + y + f, cache, None
     x = x + y
     h2 = apply_norm(p["norm2"], x, cfg)
+    here = None
     if desc.ffn == "moe":
-        f = moe_mod.moe_ffn_decode(p["ffn"], h2, cfg)
+        f, here = moe_mod.moe_ffn_decode(
+            p["ffn"], h2, cfg, matmul_shared_up=proj.get("shared_up"),
+            matmul_shared_down=proj.get("shared_down"))
     else:
         f = apply_ffn(p["ffn"], h2, cfg, **ffn_mm)
-    return x + f, cache
+    return x + f, cache, here
 
 
 def layer_cache(cfg: ModelConfig, desc: LayerDesc, batch: int, max_len: int):
@@ -390,16 +396,16 @@ class TransformerLM:
         x = embed(params["embedding"], tokens, cfg)
         new_pre = []
         for i, lp in enumerate(params["prelude"]):
-            x, nc = decode_layer(lp, x, cfg, layer_desc(cfg, i),
-                                 cache=cache["prelude"][i], pos=pos,
-                                 mrope_positions=mrope_positions)
+            x, nc, _ = decode_layer(lp, x, cfg, layer_desc(cfg, i),
+                                    cache=cache["prelude"][i], pos=pos,
+                                    mrope_positions=mrope_positions)
             new_pre.append(nc)
 
         def group_body(x, xs):
             gp, gc = xs
             new_gc = {}
             for i in range(self.period):
-                x, new_gc[f"pos{i}"] = decode_layer(
+                x, new_gc[f"pos{i}"], _ = decode_layer(
                     gp[f"pos{i}"], x, cfg, self.descs[i],
                     cache=gc[f"pos{i}"], pos=pos,
                     mrope_positions=mrope_positions)

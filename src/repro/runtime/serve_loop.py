@@ -37,7 +37,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -233,8 +233,11 @@ class ContinuousBatcher:
         cfg = model.cfg
         # ``_forward`` is the step program up to what the host reads: the
         # (B, V) f32 logits, or — round mode — the (B, d) f32 hidden state
-        # whose unembed is the real coded round.  The jitted ``_step`` adds
-        # the greedy argmax and returns the logits it read alongside.
+        # whose unembed is the real coded round, with the new cache and the
+        # rows' routing choices on held experts (None without MoE layers).
+        # The jitted ``_step`` adds the greedy argmax and returns the
+        # logits it read alongside; a model that routes returns its counts
+        # stacked under the tokens, so one copy brings both to the host.
         if self.mode == "instep":
             from ..models.coded import (build_coded_logits,
                                         coded_flop_fraction,
@@ -252,7 +255,7 @@ class ContinuousBatcher:
             def hidden(params, cache, tokens, pos):
                 h, nc = model.decode_step(params, cache, tokens, pos,
                                           return_hidden=True)
-                return h[:, 0, :].astype(jnp.float32), nc
+                return h[:, 0, :].astype(jnp.float32), nc, None
 
             self._forward = hidden
             emb = params["embedding"]
@@ -263,7 +266,7 @@ class ContinuousBatcher:
 
             def plain(params, cache, tokens, pos):
                 logits, nc = model.decode_step(params, cache, tokens, pos)
-                return logits[:, 0, :].astype(jnp.float32), nc
+                return logits[:, 0, :].astype(jnp.float32), nc, None
 
             self._forward = plain
             self.coded_fraction = 0.0
@@ -271,10 +274,13 @@ class ContinuousBatcher:
         def step(*args):
             self.trace_count += 1            # runs at trace time only
             with span("trace", fn="serve_step"):
-                out, nc = self._forward(*args)
+                out, nc, here = self._forward(*args)
                 if self.mode == "round":
                     return out, nc
-                return jnp.argmax(out, axis=-1).astype(jnp.int32), out, nc
+                nxt = jnp.argmax(out, axis=-1).astype(jnp.int32)
+                if here is not None:
+                    nxt = jnp.stack([nxt, here])
+                return nxt, out, nc
 
         self._step = jax.jit(step)
         self._warm: set = set()              # buckets already compiled
@@ -351,8 +357,9 @@ class ContinuousBatcher:
     def _run_step(self, cache, tok, pos, b):
         """One step at bucket ``b``: returns (next_tokens (b,), logits
         (b, V) — on the device, except in round mode — new cache,
-        RoundStats, virtual_dur_s, wall_s, end): ``end`` is the host clock
-        when the step's outputs were ready."""
+        RoundStats, virtual_dur_s, wall_s, end, here): ``end`` is the host
+        clock when the step's outputs were ready; ``here`` (b,) each row's
+        routing choices on held experts, or None."""
         jnp = self._jnp
         from .engine import RoundStats
         sliced = self._slice_cache(cache, b)
@@ -403,15 +410,21 @@ class ContinuousBatcher:
         cache = self._merge_cache(cache, new_cache, b)
         with span("serve.to_host"):
             nxt = np.asarray(nxt)
-        return nxt, logits, cache, stats, virt, wall, end
+        here = None
+        if nxt.ndim == 2:
+            nxt, here = nxt
+        return nxt, logits, cache, stats, virt, wall, end, here
 
     # --------------------------------------------------------------- loop
     def run(self, requests: Sequence[Request], *,
-            record_logits: bool = False) -> ServeResult:
+            record_logits: bool = False,
+            on_step: Optional[Callable[[int], None]] = None) -> ServeResult:
         """Serve ``requests`` to completion.  ``record_logits`` copies
         each step's logits to the host and returns every request's rows
         in :attr:`ServedRequest.logits` — what parity checks compare,
-        since greedy tokens flip on near-ties."""
+        since greedy tokens flip on near-ties.  ``on_step(i)`` runs on the
+        host once before step ``i`` (from 0), after its admissions: a
+        profiler started and stopped there holds whole steps."""
         t_pass = time.perf_counter()
         reqs = sorted(requests, key=lambda r: (r.arrival_s, r.rid))
         max_len = max(len(r.prompt) + r.gen for r in reqs) + 1
@@ -451,10 +464,14 @@ class ContinuousBatcher:
                 continue
 
             # ---- the bucketed step: ``live`` of its ``b`` rows serve a
-            # request (a gated batch's finished ones hold theirs idle)
+            # request (a gated batch's finished ones hold theirs idle);
+            # a model that routes adds ``routed_here``, the live rows'
+            # routing choices that fell on held experts
+            if on_step is not None:
+                on_step(len(step_stats))
             b = _next_pow2(len(slots))
             live = sum(not s.done for s in slots)
-            with span("serve.step", bucket=b, live=live):
+            with span("serve.step", bucket=b, live=live) as step_span:
                 for i, s in enumerate(slots):
                     plen = len(s.req.prompt)
                     tok[i] = (s.req.prompt[s.fed] if s.fed < plen
@@ -462,8 +479,11 @@ class ContinuousBatcher:
                     pos[i] = s.fed
                 tok[len(slots):b] = 0            # padded slots: ignored rows
                 pos[len(slots):b] = 0
-                nxt, logits, cache, stats, virt, wall, end = self._run_step(
-                    cache, tok, pos, b)
+                (nxt, logits, cache, stats, virt, wall, end,
+                 here) = self._run_step(cache, tok, pos, b)
+                if here is not None:
+                    step_span.set_metadata(routed_here=int(sum(
+                        here[i] for i, s in enumerate(slots) if not s.done)))
                 if record_logits:
                     with span("serve.to_host"):
                         logits = np.asarray(logits, np.float32)

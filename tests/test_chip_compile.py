@@ -1,6 +1,7 @@
 """The main path compiled for a described TPU v5e chip, at the shapes
-``chip_smoke.py`` runs: the Pallas kernels, the fused and the encrypted
-coded rounds and the coded serve step.
+``chip_smoke.py`` runs: the Pallas kernels (the held-experts kernel at
+Moonlight-16B-A3B's widths), the fused and the encrypted coded rounds and
+the coded serve step.
 
 Nothing here runs.  Each test lowers one program with abstract arguments
 placed on one chip of a ``v5e:2x2`` topology and compiles it with the
@@ -29,6 +30,7 @@ from repro.api import ClusterSpec, CryptoSpec, Session
 from repro.kernels import ops
 from repro.kernels.berrut_encode import berrut_encode_kernel
 from repro.kernels.coded_matmul import coded_matmul_kernel
+from repro.kernels.held_experts import held_experts_kernel
 from repro.kernels.mask_add import mask_add_kernel
 from repro.runtime import engine
 
@@ -120,6 +122,16 @@ def test_mask_add_kernel_compiles(one_chip):
                                          interpret=False).compile())
 
 
+def test_held_experts_kernel_compiles(one_chip):
+    # Moonlight-16B-A3B on one expert-parallel chip: 128 bf16 rows, 8
+    # held experts of 2048 x 1408, float32 weights
+    e, d, f = 8, 2048, 1408
+    args = shaped(one_chip, jax.ShapeDtypeStruct((128, d), jnp.bfloat16),
+                  (128, e), (e, d, f), (e, d, f), (e, f, d))
+    check_compiled(held_experts_kernel.lower(*args, interpret=False)
+                   .compile())
+
+
 # ---------------------------------------------------------- round programs
 
 def fig3_engine(**crypto):
@@ -183,7 +195,7 @@ def test_coded_serve_step_compiles(one_chip, on_tpu):
         logits = build_coded_logits(model, scheme, code)
 
         def step(*args):
-            out, cache = logits(*args)
+            out, cache, _ = logits(*args)
             return jnp.argmax(out, axis=-1).astype(jnp.int32), cache
 
         cache = jax.eval_shape(lambda: model.init_cache(4, 40))

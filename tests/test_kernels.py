@@ -141,3 +141,31 @@ def test_xla_flash_vjp_matches_dense_grads():
     g2 = jax.grad(via_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b_ in zip(g1, g2):
         assert float(jnp.max(jnp.abs(a - b_))) < 1e-5
+
+
+@pytest.mark.parametrize("b,d,e,f,tf", [(5, 64, 3, 128, 512),
+                                        (16, 128, 2, 384, 128),
+                                        (1, 64, 4, 96, 512)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_held_experts_kernel_matches_oracle(b, d, e, f, tf, dtype):
+    """The held-experts kernel in interpret mode against its XLA twin:
+    rows that chose no held expert (zero gates) get zero, the f axis
+    tiled (384 in 128s) or whole (96, not a lane multiple), rows padded
+    to the sublane tile."""
+    from repro.kernels.held_experts import held_experts_kernel
+    r = np.random.default_rng(b * 100 + e)
+    x = jnp.asarray(r.standard_normal((b, d)), dtype)
+    gates = r.uniform(0.1, 1.0, (b, e)) * (r.random((b, e)) < 0.6)
+    gates[0] = 0.0                                   # routed elsewhere
+    gates = jnp.asarray(gates, jnp.float32)
+    wg, wu = (jnp.asarray(r.standard_normal((e, d, f)) / np.sqrt(d),
+                          jnp.float32) for _ in range(2))
+    wd = jnp.asarray(r.standard_normal((e, f, d)) / np.sqrt(f), jnp.float32)
+    got = held_experts_kernel(x, gates, wg, wu, wd, tf=tf, interpret=True)
+    want = ref.held_experts(x, gates, wg, wu, wd)
+    assert got.shape == (b, d) and got.dtype == jnp.float32
+    # the same products in the same dtype, accumulated in float32 in
+    # another order (tile by tile)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5 * float(jnp.abs(want).max()))
+    np.testing.assert_array_equal(np.asarray(got[0]), 0.0)

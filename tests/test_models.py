@@ -94,7 +94,8 @@ def test_cache_specs_match_structure(arch):
 
 
 @pytest.mark.parametrize("arch", ["qwen2-7b", "deepseek-v2-lite-16b",
-                                  "command-r-35b", "jamba-v0.1-52b"])
+                                  "moonlight-16b-a3b", "command-r-35b",
+                                  "jamba-v0.1-52b"])
 def test_decode_matches_forward_causal(arch):
     """Teacher-forced forward logits at position t == incremental decode
     logits — exercises the GQA cache, the MLA absorbed-decode algebra, the

@@ -20,7 +20,8 @@ from repro.runtime.spans import SPANS
 
 SRC = Path(__file__).resolve().parents[1] / "src" / "repro"
 # the counters a span may carry, each read by a benchmark metric or a test
-COUNTERS = {"bucket", "live", "new_bucket", "fn", "chunked", "bytes"}
+COUNTERS = {"bucket", "live", "new_bucket", "fn", "chunked", "bytes",
+            "routed_here"}
 
 SPEC = ClusterSpec(
     code=CodeSpec(scheme="spacdc", n_workers=10, k_blocks=4),
@@ -52,6 +53,12 @@ def _profiled(log_dir, fn):
         out = fn()
     finally:
         jax.profiler.stop_trace()
+    return out, _recorded(log_dir)
+
+
+def _recorded(log_dir):
+    """Every ``spacdc.`` host event of the newest trace under ``log_dir``,
+    as (line, start, end, name, stats), in order."""
     path = sorted(log_dir.glob("plugins/profile/*/*.xplane.pb"))[-1]
     events = []
     for plane in ProfileData.from_file(str(path)).planes:
@@ -61,7 +68,7 @@ def _profiled(log_dir, fn):
                     events.append((line.name, e.start_ns,
                                    e.start_ns + e.duration_ns, e.name,
                                    dict(e.stats)))
-    return out, sorted(events, key=lambda e: (e[1], -e[2]))
+    return sorted(events, key=lambda e: (e[1], -e[2]))
 
 
 def _inside(events, outer, name):
@@ -133,6 +140,56 @@ def test_trace_span_marks_only_the_rounds_that_compiled(rounds):
     traced = [[t[4] for t in _inside(events, d, "spacdc.trace")]
               for d in disp]
     assert traced == [[{"fn": "fused_round"}], [], [{"fn": "fused_round"}]]
+
+
+@pytest.mark.parametrize("held", [(), (2, 4)])
+def test_step_span_counts_the_choices_routed_to_held_experts(tmp_path, held):
+    """A model that routes puts ``routed_here`` on each step span: its
+    live rows' choices that fell on held experts, over the MoE layers —
+    every choice where all experts are held, a part where half are."""
+    import dataclasses
+    from repro.configs import tiny_config
+    cfg = dataclasses.replace(tiny_config("moonlight-16b-a3b"),
+                              held_experts=held)
+    n_moe = cfg.n_layers - cfg.first_dense_layers
+
+    def run():
+        with Session(SPEC) as s:
+            return s.batcher(cfg).run(_backlog())
+
+    res, events = _profiled(tmp_path, run)
+    steps = [ev[4] for ev in events if ev[3] == "spacdc.serve.step"]
+    assert len(steps) == res.n_steps
+    full = [st["live"] * cfg.top_k * n_moe for st in steps]
+    here = [st["routed_here"] for st in steps]
+    if not held:
+        assert here == full
+    else:
+        assert all(0 <= h <= f for h, f in zip(here, full))
+        assert 0 < sum(here) < sum(full)
+
+
+def test_a_profiler_run_between_steps_holds_whole_steps(tmp_path):
+    """``on_step(i)`` runs once before each step: a profiler started
+    before step 1 and stopped before step 3 records the step spans of
+    steps 1 and 2 alone, whole, with their own counters."""
+    seen = []
+
+    def on_step(i):
+        seen.append(i)
+        if i == 1:
+            jax.profiler.start_trace(str(tmp_path))
+        elif i == 3:
+            jax.profiler.stop_trace()
+
+    with Session(SPEC) as s:
+        bat = s.batcher(tiny=True)
+        bat.run(_backlog())                   # compiles every bucket
+        res = bat.run(_backlog(), on_step=on_step)
+    assert seen == list(range(res.n_steps)) and res.n_steps > 3
+    steps = [ev for ev in _recorded(tmp_path)
+             if ev[3] == "spacdc.serve.step"]
+    assert [ev[4]["bucket"] for ev in steps] == res.buckets[1:3].tolist()
 
 
 def test_one_step_span_per_step_with_the_backlog_slot_fill(served):
